@@ -93,7 +93,15 @@ def _request_trace(ds, seed: int = 0) -> np.ndarray:
 
 
 def _spawn_child(payload: dict) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
+    """Serve in a child on the parent's platform. A device belongs to one
+    process, so the parent must not have started a JAX backend yet."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "bench_ooc's serving children need the device, but this process "
+            "already holds it: run bench_ooc before any other JAX work "
+            "(benchmarks/run.py runs it first, or REPRO_BENCH_ONLY=bench_ooc)")
+    env = dict(os.environ,
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(os.path.dirname(__file__), "..", "src"),
                     os.environ.get("PYTHONPATH", "")]))

@@ -37,11 +37,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from repro.ioutil import atomic_write_json  # noqa: E402  (needs src on path)
 
 MODULES = [
+    # first: its serving children need the device before this process
+    # starts a JAX backend
+    "bench_ooc",
     "bench_kernels",
     "bench_memory",
     "bench_inference",
     "bench_update",
-    "bench_ooc",
     "bench_training",
     "bench_ablation",
     "bench_scheduling",
@@ -113,8 +115,12 @@ def _write_bench_json(mod_name, mod, rows) -> None:
           f"{len(kept)} kept records)", file=sys.stderr, flush=True)
 
 
-def main() -> None:
+def main() -> int:
+    """Run every selected module; exit non-zero if any of them failed."""
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = os.environ.get("REPRO_BENCH_ONLY", "")
+    failed = []
     print("name,us_per_call,derived")
     for mod_name in MODULES:
         if only and only not in mod_name:
@@ -130,9 +136,13 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc(file=sys.stderr)
             print(f"{mod_name}/ERROR,0,{type(e).__name__}", flush=True)
+            failed.append(mod_name)
         print(f"# {mod_name} done in {time.time()-t0:.1f}s", file=sys.stderr,
               flush=True)
+    if failed:
+        print(f"# FAILED: {', '.join(failed)}", file=sys.stderr, flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -19,9 +19,11 @@ config fingerprint:
   Low-fill batches (scattered adjacency the reordering could not bunch)
   stay on the segment path; a plan can mix.
 * **Feature-tile width block_f** (per batch): the widest
-  ``tune_block_fs`` candidate whose fused-kernel working set — one K-row
-  of value tiles + double-buffered x stripes + the output block — fits the
-  ``tune_vmem_kb`` budget.
+  ``tune_block_fs`` candidate whose fused-kernel VMEM footprint —
+  double-buffered value tiles and x stripes, the double-buffered output
+  block and the accumulator (``kernels.spmm.fused.fused_vmem_bytes``) —
+  fits the ``tune_vmem_kb`` budget. A batch whose kernel does not fit
+  even at the 128-lane default goes to segment.
 
 The streaming (out-of-core) builder makes the SAME decisions from the same
 inputs (``repro.ooc.stream``), so resident and streamed plans stay
@@ -35,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.batches import PaddedBatch
+from repro.kernels.spmm.fused import LANES, fused_vmem_bytes
 
 
 def tile_shape_stats(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
@@ -71,17 +74,13 @@ def pick_tile_block(costs: Dict[int, int]) -> int:
 
 def tune_block_f(k: int, block: int, candidates: Sequence[int],
                  vmem_kb: int) -> int:
-    """Widest feature-tile width whose fused-kernel working set fits the
-    VMEM budget: one (K, B, B) row of value tiles, ``nbuf`` (B, block_f)
-    x stripes, and the (B, block_f) output accumulator, all float32."""
-    if not candidates:
-        return 0
-    nbuf = 2 if k > 1 else 1
+    """Widest feature-tile width whose fused-kernel VMEM footprint
+    (``fused_vmem_bytes``) fits the budget; 0 — the kernel's 128-lane
+    default — when no candidate fits or none is given."""
     budget = int(vmem_kb) * 1024
-    vals = 4 * k * block * block
     fit = [c for c in sorted(int(c) for c in candidates)
-           if vals + 4 * (nbuf + 1) * block * c <= budget]
-    return fit[-1] if fit else int(min(int(c) for c in candidates))
+           if fused_vmem_bytes(block, c, k) <= budget]
+    return fit[-1] if fit else 0
 
 
 def batch_tile_stats(batch: PaddedBatch) -> dict:
@@ -101,13 +100,19 @@ def batch_tile_stats(batch: PaddedBatch) -> dict:
     return out
 
 
-def decide_backend(stats: dict, auto_kappa: float) -> str:
+def decide_backend(stats: dict, auto_kappa: float,
+                   vmem_kb: int = 8192) -> str:
     """bcsr iff the padded tile flops stay within ``auto_kappa`` of the
     segment path's exact per-edge work (equivalently: tile fill is at
-    least 1/kappa of dense). Batches without tiles have no choice."""
+    least 1/kappa of dense) and the fused kernel fits the VMEM budget at
+    its narrowest, 128-lane feature tile. Batches without tiles have no
+    choice."""
     if "nonzero_tiles" not in stats:
         return "segment"
     block = stats["block"]
+    if fused_vmem_bytes(block, LANES, stats["max_tiles_per_row"]) \
+            > int(vmem_kb) * 1024:
+        return "segment"
     padded = stats["nonzero_tiles"] * block * block
     return "bcsr" if padded <= auto_kappa * max(stats["edges"], 1) else "segment"
 
@@ -128,7 +133,8 @@ def decide_batches(batches: Sequence[PaddedBatch], cfg
             backend = cfg.backend if cfg.backend in ("segment", "dense") \
                 else "segment"
         elif getattr(cfg, "autotune", True):
-            backend = decide_backend(s, getattr(cfg, "auto_kappa", 16.0))
+            backend = decide_backend(s, getattr(cfg, "auto_kappa", 16.0),
+                                     getattr(cfg, "tune_vmem_kb", 8192))
         else:
             backend = "bcsr"
         bf = 0

@@ -60,7 +60,8 @@ def batch_wise_aux(
         b = budget if budget is not None else len(batch)
         row = pi[i]
         k = min(b, (row > 0).sum())
-        top = np.argpartition(-row, k - 1)[:k] if k > 0 else np.zeros(0, np.int64)
+        # stable: argpartition's ties at the k-th score depend on the CPU
+        top = np.argsort(-row, kind="stable")[:k]
         aux = np.unique(np.concatenate([top.astype(np.int32),
                                         np.asarray(batch, dtype=np.int32)]))
         out.append(aux.astype(np.int32))

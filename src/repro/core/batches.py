@@ -107,6 +107,43 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+def _reverse_cuthill_mckee(indptr: np.ndarray, indices: np.ndarray
+                           ) -> np.ndarray:
+    """Reverse Cuthill-McKee order of a symmetric CSR adjacency.
+
+    scipy's algorithm (BFS from each unvisited lowest-degree node, each
+    node's new neighbours in ascending degree), with every degree tie
+    broken by node id. scipy's own ``reverse_cuthill_mckee`` ranks its seeds
+    with numpy's default argsort, whose ties depend on the SIMD sort the
+    CPU dispatches to: the same batch got a different order, and a
+    different tile count K, on hosts with and without AVX-512.
+    """
+    n = len(indptr) - 1
+    deg = np.diff(indptr)
+    seen = np.zeros(n, bool)
+    order = np.empty(n, np.int64)
+    head = tail = 0
+    for s in np.argsort(deg, kind="stable"):
+        if seen[s]:
+            continue
+        seen[s] = True
+        order[tail] = s
+        tail += 1
+        while head < tail:
+            i = order[head]
+            head += 1
+            nbr = indices[indptr[i]:indptr[i + 1]]
+            nbr = nbr[~seen[nbr]]
+            if len(nbr):
+                nbr = nbr[np.argsort(deg[nbr], kind="stable")]
+                seen[nbr] = True
+                order[tail:tail + len(nbr)] = nbr
+                tail += len(nbr)
+        if tail == n:
+            break
+    return order[::-1].copy()
+
+
 def batch_node_order(num_nodes: int, src: np.ndarray, dst: np.ndarray,
                      mode: str = "bfs") -> np.ndarray:
     """Batch-local node reordering permutation (DESIGN.md §7).
@@ -124,9 +161,7 @@ def batch_node_order(num_nodes: int, src: np.ndarray, dst: np.ndarray,
                       shape=(num_nodes, num_nodes))
     a = (a + a.T).tocsr()
     if mode == "bfs":
-        from scipy.sparse.csgraph import reverse_cuthill_mckee
-        return np.asarray(reverse_cuthill_mckee(a, symmetric_mode=True),
-                          dtype=np.int64)
+        return _reverse_cuthill_mckee(a.indptr, a.indices)
     if mode == "degree":
         return np.argsort(-np.diff(a.indptr), kind="stable").astype(np.int64)
     raise ValueError(f"unknown reorder mode: {mode}")

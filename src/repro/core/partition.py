@@ -93,7 +93,9 @@ def ppr_distance_partition(
     ws = val.ravel()[keep]
     uf = _UnionFind(n_out)
     if len(ws):
-        order = np.argsort(-ws)
+        # stable: numpy's default sort is dispatched per CPU and breaks
+        # ties differently across hosts, which changed the plan
+        order = np.argsort(-ws, kind="stable")
         us = us[order]; vs = vs[order]
         for u, v in zip(us, vs):
             uf.union_capped(int(u), int(v), max_outputs_per_batch)
@@ -134,7 +136,7 @@ def _fennel(g: CSRGraph, num_parts: int, gamma: float = 1.5,
     cap = int(1.1 * n / num_parts) + 1
     assign = np.full(n, -1, dtype=np.int64)
     sizes = np.zeros(num_parts, dtype=np.int64)
-    order = np.argsort(-g.degrees())
+    order = np.argsort(-g.degrees(), kind="stable")   # host-independent ties
     nbr_count = np.zeros(num_parts, dtype=np.float64)
     for v in order:
         nbr_count[:] = 0.0

@@ -40,7 +40,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist.sharding import data_axes, fit_spec, tree_shardings
@@ -282,7 +282,7 @@ class ShardedPlanExecutor:
             grads, losses = shard_map(
                 train_body, mesh=mesh,
                 in_specs=(P_rep, P_dp, P_dp, P_dp),
-                out_specs=(P_rep, P_dp), check_rep=False)(
+                out_specs=(P_rep, P_dp), check_vma=False)(
                 params, batch, weights, rngs)
             updates, opt_state = self.opt.update(
                 grads, opt_state, params, lr)
@@ -293,7 +293,7 @@ class ShardedPlanExecutor:
             return shard_map(
                 eval_body, mesh=mesh,
                 in_specs=(P_rep, P_dp, P_dp),
-                out_specs=(P_dp, P_dp, P_dp), check_rep=False)(
+                out_specs=(P_dp, P_dp, P_dp), check_vma=False)(
                 params, batch, weights)
 
         @jax.jit
@@ -301,7 +301,7 @@ class ShardedPlanExecutor:
             return shard_map(
                 fwd_body, mesh=mesh,
                 in_specs=(P_rep, P_dp),
-                out_specs=P_dp, check_rep=False)(params, batch)
+                out_specs=P_dp, check_vma=False)(params, batch)
 
         return SuperstepFns(train_superstep, eval_superstep,
                             forward_superstep)

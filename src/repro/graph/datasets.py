@@ -6,15 +6,20 @@ done once, cached, and re-used across runs/models/seeds.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import json
 import os
-from typing import Dict, Optional
+from typing import Dict
 
 import numpy as np
 
 from repro.graph.csr import CSRGraph, gcn_preprocess
-from repro.graph.synthetic import DATASET_SPECS, make_sbm_dataset
+from repro.graph.synthetic import DATASET_SPECS, SyntheticSpec, make_sbm_dataset
 
-_CACHE_DIR = os.environ.get("REPRO_DATA_DIR", "/root/repo/.data_cache")
+# <checkout>/.data_cache: src/repro/graph/datasets.py is four levels down
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".data_cache")
 
 
 @dataclasses.dataclass
@@ -42,11 +47,21 @@ class GraphDataset:
 _MEMO: Dict[str, GraphDataset] = {}
 
 
+def cache_path(spec: SyntheticSpec) -> str:
+    """The on-disk cache file of ``spec``'s dataset. Its name carries a hash
+    of every field of the spec, so an edited spec never loads a file that
+    an older one wrote."""
+    blob = json.dumps(dataclasses.asdict(spec), sort_keys=True).encode()
+    digest = hashlib.sha256(blob).hexdigest()[:16]
+    root = os.environ.get("REPRO_DATA_DIR") or _DEFAULT_CACHE_DIR
+    return os.path.join(root, f"{spec.name}-{digest}.npz")
+
+
 def get_dataset(name: str, cache: bool = True) -> GraphDataset:
-    if name in _MEMO:
-        return _MEMO[name]
     spec = DATASET_SPECS[name]
-    path = os.path.join(_CACHE_DIR, f"{name}-v1.npz")
+    path = cache_path(spec)
+    if path in _MEMO:
+        return _MEMO[path]
     if cache and os.path.exists(path):
         z = np.load(path, allow_pickle=False)
         g = CSRGraph(z["indptr"], z["indices"], z["weights"])
@@ -58,7 +73,7 @@ def get_dataset(name: str, cache: bool = True) -> GraphDataset:
         ng = gcn_preprocess(g)
         ds = GraphDataset(name, g, ng, feats, labels, splits)
         if cache:
-            os.makedirs(_CACHE_DIR, exist_ok=True)
+            os.makedirs(os.path.dirname(path), exist_ok=True)
             np.savez_compressed(
                 path,
                 indptr=g.indptr, indices=g.indices,
@@ -66,5 +81,5 @@ def get_dataset(name: str, cache: bool = True) -> GraphDataset:
                 n_indptr=ng.indptr, n_indices=ng.indices, n_weights=ng.weights,
                 features=ds.features, labels=ds.labels,
                 train=splits["train"], val=splits["val"], test=splits["test"])
-    _MEMO[name] = ds
+    _MEMO[path] = ds
     return ds

@@ -9,13 +9,14 @@ computation*, not a tensor, so fuse it into the SpMM's operand fetch.
 Two implementations share this contract, ``out = A @ x`` over padded
 block-CSR tiles, without ever materializing the gathered matrix:
 
-* ``spmm_bcsr_fused_pallas`` — the TPU kernel. ``x`` stays in HBM
-  (``memory_space=ANY``); the kernel loops over a row-tile's K column
-  tiles, issuing an explicit ``make_async_copy`` per (B, BF) feature
-  stripe into a double-buffered VMEM scratch, overlapping the next
-  stripe's DMA with the current MXU ``dot``. Each feature stripe crosses
-  VMEM exactly once per consuming tile; the (R, K, B, F) intermediate
-  never exists. Validated in interpret mode on CPU (tier-1/CI).
+* ``spmm_bcsr_fused_pallas`` — the TPU kernel. ``x`` and the value tiles
+  stay in HBM (``memory_space=ANY``); the kernel loops over a row-tile's
+  K column tiles, issuing an explicit ``make_async_copy`` per (B, B)
+  value tile and per (B, BF) feature stripe into double-buffered VMEM
+  scratch, overlapping the next tile's DMAs with the current MXU ``dot``.
+  Each feature stripe crosses VMEM exactly once per consuming tile; the
+  (R, K, B, F) intermediate never exists. Tested in interpret mode on the
+  CPU and compiled for a described TPU v5e in ``tests/test_tpu_compile.py``.
 
 * ``spmm_bcsr_stream`` — the compiled off-TPU production path: a
   ``lax.scan`` over tile slots whose carry is the (R, B, F) accumulator.
@@ -62,30 +63,50 @@ def spmm_bcsr_stream(tile_cols: jnp.ndarray, tile_vals: jnp.ndarray,
     return acc.reshape(r * b, f)
 
 
+# Mosaic tiles the last dim of a VMEM/HBM slice in 128 lanes: a DMA stripe
+# narrower than that (or not a multiple of it) is refused at compile time.
+LANES = 128
+
+
+def fused_vmem_bytes(block: int, block_f: int, k: int) -> int:
+    """VMEM the fused kernel holds for one grid step, in bytes: ``nbuf``
+    (B, B) value tiles and ``nbuf`` (B, BF) x stripes of DMA scratch, the
+    (B, BF) output block that the pipeline double-buffers, and the (B, BF)
+    float32 accumulator. It does not grow with K: value tiles stream
+    through the same double buffer as the x stripes."""
+    nbuf = 2 if k > 1 else 1
+    return 4 * (nbuf * block * block + (nbuf + 2 + 1) * block * block_f)
+
+
 def _fused_kernel(k, b, bf, nbuf,
-                  cols_ref, vals_ref, x_any, out_ref, xbuf, sem):
+                  cols_ref, vals_any, x_any, out_ref, vbuf, xbuf, sem):
     ri = pl.program_id(0)
     fi = pl.program_id(1)
 
-    def stripe_copy(ki, slot):
-        # the gather, fused: an indexed DMA of x's (B, BF) stripe for
-        # column tile cols[ri, ki] straight from HBM into VMEM scratch
+    def tile_copies(ki, slot):
+        # the gather, fused: indexed DMAs of value tile (ri, ki) and of x's
+        # (B, BF) stripe for column tile cols[ri, ki], HBM → VMEM scratch
         c = cols_ref[ri, ki]
-        return pltpu.make_async_copy(
-            x_any.at[pl.ds(c * b, b), pl.ds(fi * bf, bf)],
-            xbuf.at[slot], sem.at[slot])
+        return (pltpu.make_async_copy(vals_any.at[ri, ki], vbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(
+                    x_any.at[pl.ds(c * b, b), pl.ds(fi * bf, bf)],
+                    xbuf.at[slot], sem.at[1, slot]))
 
-    stripe_copy(0, 0).start()
+    for cp in tile_copies(0, 0):
+        cp.start()
 
     def body(ki, acc):
         slot = jax.lax.rem(ki, nbuf)
 
         @pl.when(ki + 1 < k)
-        def _prefetch():                 # overlap next DMA with this dot
-            stripe_copy(ki + 1, jax.lax.rem(ki + 1, nbuf)).start()
+        def _prefetch():                 # overlap next DMAs with this dot
+            for cp in tile_copies(ki + 1, jax.lax.rem(ki + 1, nbuf)):
+                cp.start()
 
-        stripe_copy(ki, slot).wait()
-        return acc + jnp.dot(vals_ref[0, ki], xbuf[slot],
+        for cp in tile_copies(ki, slot):
+            cp.wait()
+        return acc + jnp.dot(vbuf[slot], xbuf[slot],
                              preferred_element_type=jnp.float32)
 
     acc = jax.lax.fori_loop(0, k, body, jnp.zeros((b, bf), jnp.float32))
@@ -99,31 +120,39 @@ def spmm_bcsr_fused_pallas(tile_cols: jnp.ndarray, tile_vals: jnp.ndarray,
     """tile_cols (R, K) int32; tile_vals (R, K, B, B); x (C·B, F) → (R·B, F).
 
     Grid (R, F/BF): one kernel invocation owns one (B, BF) output block and
-    loops K internally, so the output block is written once and the x
-    stripes it needs are fetched by explicit double-buffered DMA — the
-    fused-gather contract. ``vals`` rides in via an ordinary (1, K, B, B)
-    BlockSpec (the whole row-tile of values resident per step); ``x`` is
-    left unblocked in HBM and only touched by the in-kernel copies.
+    loops K internally, so the output block is written once. Both ``vals``
+    and ``x`` stay in HBM; each K step fetches its (B, B) value tile and
+    the (B, BF) x stripe it multiplies by explicit double-buffered DMA —
+    the fused-gather contract — so VMEM use is independent of K
+    (``fused_vmem_bytes``). A feature width that is not a multiple of
+    ``LANES`` is zero-padded to one and sliced back off the result.
     """
     r, k, b, _ = tile_vals.shape
     f = x.shape[1]
-    bf = min(block_f, f)
-    assert f % bf == 0, f"feature dim {f} not divisible by block_f {bf}"
+    fp = -(-f // LANES) * LANES
+    bf = min(block_f, fp)
+    if fp % bf:
+        raise ValueError(f"padded feature dim {fp} not divisible by "
+                         f"block_f {bf}")
+    if fp != f:
+        x = jnp.pad(x, ((0, 0), (0, fp - f)))
     nbuf = 2 if k > 1 else 1
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_fused_kernel, k, b, bf, nbuf),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(r, f // bf),
+            grid=(r, fp // bf),
             in_specs=[
-                pl.BlockSpec((1, k, b, b), lambda ri, fi, cols: (ri, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((b, bf), lambda ri, fi, cols: (ri, fi)),
-            scratch_shapes=[pltpu.VMEM((nbuf, b, bf), jnp.float32),
-                            pltpu.SemaphoreType.DMA((nbuf,))],
+            scratch_shapes=[pltpu.VMEM((nbuf, b, b), tile_vals.dtype),
+                            pltpu.VMEM((nbuf, b, bf), x.dtype),
+                            pltpu.SemaphoreType.DMA((2, nbuf))],
         ),
-        out_shape=jax.ShapeDtypeStruct((r * b, f), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((r * b, fp), x.dtype),
         interpret=interpret,
     )(tile_cols, tile_vals, x)
+    return out[:, :f] if fp != f else out

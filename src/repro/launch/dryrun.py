@@ -333,9 +333,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 def _finish(arch, shape_name, mesh_kind, n_chips, cfg, shape, compiled,
             t_lower, t_compile, hlo_dir, model_flops_override=None,
             verbose=False) -> Dict[str, Any]:
-    from repro.launch.hlo_analysis import analyze_hlo, xla_cost_analysis
+    from repro.launch.hlo_analysis import analyze_hlo
     mem = compiled.memory_analysis()
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     if hlo_dir:
         save_hlo(hlo_text, hlo_dir, f"{arch}__{shape_name}__{mesh_kind}")

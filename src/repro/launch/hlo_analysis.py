@@ -303,13 +303,3 @@ class HLOAnalysis:
 
 def analyze_hlo(text: str) -> Dict[str, float]:
     return HLOAnalysis(text).entry_cost()
-
-
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """`compiled.cost_analysis()` normalized across jaxlib versions: older
-    releases return a one-element list of dicts (one per executable), newer
-    ones return the dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost

@@ -11,7 +11,8 @@ Aggregation runs on one of three backends (DESIGN.md §7):
 * "segment" — COO gather + ``segment_sum`` (reference; XLA scatter-add).
 * "bcsr"    — the Pallas block-CSR SpMM over the tiles that preprocessing
               emitted (``core.batches.build_batches(bcsr_block=...)``):
-              compiled Pallas on TPU, interpret mode elsewhere.
+              the fused Pallas kernel on TPU, the compiled streaming SpMM
+              elsewhere.
 * "dense"   — materialize the (N, N) batch adjacency and matmul; the
               MXU-roofline upper bound the tiled kernel is judged against.
 
@@ -97,28 +98,29 @@ def validate_batch_for_backend(batch, backend: str, kind: str = "gcn") -> str:
     return b
 
 
+def _spmm_impl() -> str:
+    """SpMM impl for this process's backend: the fused gather+SpMM Pallas
+    kernel on TPU, the compiled streaming path everywhere else."""
+    return "fused" if jax.default_backend() == "tpu" else "stream"
+
+
 def _spmm_tiles(tile_cols: jnp.ndarray, tile_vals: jnp.ndarray,
                 x: jnp.ndarray, block_f: int = 0) -> jnp.ndarray:
     """A @ x through the symmetric-adjacency SpMM (DESIGN.md §7/§14).
 
-    On TPU this is the fused gather+SpMM Pallas kernel; everywhere else the
-    compiled streaming path (the old CPU fallback ran the Pallas kernel in
-    interpret mode — the reason bcsr lost to segment in the benches).
     ``block_f`` is the autotuner's tuned feature-tile width; 0 (or a width
     that does not divide the live feature dim — hidden dims vary per layer)
-    falls back to the 128-lane default.
+    falls back to the 128-lane default. The fused kernel zero-pads a width
+    that is not a multiple of 128 (e.g. a class-count output layer) and
+    slices the result back.
     """
     from repro.kernels.spmm.ops import spmm_bcsr_sym
     r, _, b, _ = tile_vals.shape
     assert r * b == x.shape[0], (
         f"bcsr tiles cover {r * b} rows but h has {x.shape[0]}")
     f = x.shape[1]
-    if block_f and f % block_f == 0:
-        bf = int(block_f)
-    else:
-        bf = 128 if f % 128 == 0 else f
-    impl = "fused" if jax.default_backend() == "tpu" else "stream"
-    return spmm_bcsr_sym(tile_cols, tile_vals, x, impl, bf)
+    bf = int(block_f) if block_f and f % block_f == 0 else 128
+    return spmm_bcsr_sym(tile_cols, tile_vals, x, _spmm_impl(), bf)
 
 
 def _dense_adj(n: int, edge_src: jnp.ndarray, edge_dst: jnp.ndarray,

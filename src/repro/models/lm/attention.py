@@ -22,11 +22,13 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.dist import annotate
 from repro.models.lm.common import apply_rope
 
-NEG_INF = jnp.float32(-1e30)
+# a host scalar: a jnp constant here would start a JAX backend at import
+NEG_INF = np.float32(-1e30)
 
 
 def pick_chunk(s: int, target: int) -> int:

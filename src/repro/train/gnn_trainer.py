@@ -42,7 +42,7 @@ class NonFiniteGradError(RuntimeError):
 
 @dataclasses.dataclass
 class TrainResult:
-    params: Dict
+    params: Dict                 # best-validation params; the last ones if no eval ran
     history: List[Dict]          # per-epoch metrics
     best_val_acc: float
     best_epoch: int
@@ -315,7 +315,9 @@ class GNNTrainer:
 
         history: List[Dict] = []
         best_val_loss, best_val_acc, best_epoch = float("inf"), 0.0, -1
-        best_params = params
+        # None until the first eval: the step functions donate `params`,
+        # so holding the initial tree here would keep freed buffers
+        best_params = None
         bad = 0
         epoch_times = []
         t_total0 = time.time()
@@ -417,7 +419,8 @@ class GNNTrainer:
                     if bad >= self.early_stop_patience:
                         break
         return TrainResult(
-            params=best_params, history=history, best_val_acc=best_val_acc,
+            params=params if best_params is None else best_params,
+            history=history, best_val_acc=best_val_acc,
             best_epoch=best_epoch,
             time_per_epoch=float(np.mean(epoch_times)) if epoch_times else 0.0,
             preprocess_time=preprocess_time, total_time=time.time() - t_total0)

@@ -65,6 +65,29 @@ def test_backend_gradient_matches_segment(tiny_ds, bcsr_batches, kind):
         np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), atol=1e-4)
 
 
+@pytest.mark.parametrize("width", [40, 47])
+def test_fused_padded_lanes_match_segment(bcsr_batches, monkeypatch, width):
+    """A feature width that is not a multiple of 128 (a class-count output
+    layer) runs the fused kernel on zero-padded lanes: forward and gradient
+    match the segment reference. Steered to the interpret-mode kernel, as
+    ``_spmm_tiles`` would pick the compiled one on TPU."""
+    from repro.models.gnn import ops as gnn_ops
+    monkeypatch.setattr(gnn_ops, "_spmm_impl", lambda: "fused_interpret")
+    bd = bcsr_batches[0].device_arrays()
+    n = bd["features"].shape[0]
+    h = np.random.default_rng(width).normal(size=(n, width)).astype(np.float32)
+    g = np.random.default_rng(width + 1).normal(
+        size=(n, width)).astype(np.float32)
+    outs = {}
+    for be in ("segment", "bcsr"):
+        agg = lambda x: gnn_ops.weighted_agg_backend(x, bd, be)
+        out, vjp = jax.vjp(agg, h)
+        outs[be] = (np.asarray(out), np.asarray(vjp(g)[0]))
+    assert outs["bcsr"][0].shape == (n, width)
+    for got, want in zip(outs["bcsr"], outs["segment"]):
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
 def test_bcsr_backend_requires_tiles(tiny_ds):
     pipe = IBMBPipeline(tiny_ds, IBMBConfig(
         variant="node", k_per_output=8, max_outputs_per_batch=64,
@@ -204,6 +227,44 @@ def test_batch_node_order_modes():
         assert sorted(perm.tolist()) == list(range(64))
     with pytest.raises(ValueError):
         batch_node_order(64, src, dst, mode="zigzag")
+
+
+def _rcm_reference(n, src, dst):
+    """Reverse Cuthill-McKee with Python's sort: seeds and each node's new
+    neighbours ranked by (degree, node id)."""
+    nbrs = [set() for _ in range(n)]
+    for u, v in zip(src.tolist(), dst.tolist()):
+        nbrs[u].add(v)
+        nbrs[v].add(u)
+    deg = [len(s) for s in nbrs]
+    seen, order = set(), []
+    for s in sorted(range(n), key=lambda v: (deg[v], v)):
+        if s in seen:
+            continue
+        seen.add(s)
+        order.append(s)
+        head = len(order) - 1
+        while head < len(order):
+            new = sorted((v for v in nbrs[order[head]] if v not in seen),
+                         key=lambda v: (deg[v], v))
+            seen.update(new)
+            order.extend(new)
+            head += 1
+    return order[::-1]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_bfs_order_breaks_degree_ties_by_id(seed):
+    """The "bfs" order is a function of the graph alone: every degree tie
+    breaks by node id, never by how the CPU's SIMD sort orders equal keys
+    (which made plans, and their tile count K, differ between hosts)."""
+    rng = np.random.default_rng(seed)
+    n = 300
+    src = rng.integers(0, n, 600)
+    dst = rng.integers(0, n, 600)
+    keep = src != dst            # no self loops; some nodes stay isolated
+    perm = batch_node_order(n, src[keep], dst[keep], mode="bfs")
+    assert perm.tolist() == _rcm_reference(n, src[keep], dst[keep])
 
 
 def test_asymmetric_adjacency_rejected():

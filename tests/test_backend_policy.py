@@ -148,18 +148,41 @@ def test_tune_block_f_budget():
     # k=1 single-buffers; everything fits a generous budget → widest wins
     assert autotune.tune_block_f(1, 64, (128, 256, 512), 8192) == 512
     # shrink the budget until only the narrowest candidate fits:
-    # vals = 4*4*64*64 = 64KiB, per-bf cost = 4*3*64*bf
-    assert autotune.tune_block_f(4, 64, (128, 256, 512), 160) == 128
-    # nothing fits → the narrowest candidate anyway (never 0 tiles wide)
-    assert autotune.tune_block_f(4, 64, (128, 256), 1) == 128
+    # footprint = 4*(2*64*64 + 5*64*bf) → 192KiB at 128, 352KiB at 256
+    assert autotune.tune_block_f(4, 64, (128, 256, 512), 200) == 128
+    # nothing fits, or nothing to choose from → 0, the 128-lane default
+    assert autotune.tune_block_f(4, 64, (128, 256), 1) == 0
     assert autotune.tune_block_f(4, 64, (), 8192) == 0
 
 
+@pytest.mark.parametrize("block,k,vmem_kb", [
+    (128, 1, 8192), (128, 75, 8192), (128, 4096, 8192), (128, 8, 300),
+    (128, 8, 200), (512, 8, 8192), (1024, 8, 8192), (64, 2, 200)])
+def test_autotune_agrees_with_kernel_vmem(block, k, vmem_kb):
+    """The tuned width and the backend decision both follow the fused
+    kernel's own VMEM footprint, which does not grow with K."""
+    from repro.kernels.spmm.fused import LANES, fused_vmem_bytes
+    budget = vmem_kb * 1024
+    assert fused_vmem_bytes(block, LANES, k) == \
+        fused_vmem_bytes(block, LANES, max(k, 2)) or k == 1
+    cands = (128, 256, 512)
+    bf = autotune.tune_block_f(k, block, cands, vmem_kb)
+    fits = [c for c in cands if fused_vmem_bytes(block, c, k) <= budget]
+    assert bf == (max(fits) if fits else 0)
+    s = dict(edges=10**9, block=block, nonzero_tiles=1, max_tiles_per_row=k)
+    want = "bcsr" if fused_vmem_bytes(block, LANES, k) <= budget \
+        else "segment"
+    assert autotune.decide_backend(s, 16.0, vmem_kb) == want
+
+
 def test_decide_backend_kappa_threshold():
-    s = dict(edges=100, block=16, nonzero_tiles=4)   # padded flops 1024
+    s = dict(edges=100, block=16, nonzero_tiles=4,    # padded flops 1024
+             max_tiles_per_row=2)
     assert autotune.decide_backend(s, 16.0) == "bcsr"     # 1024 <= 1600
     assert autotune.decide_backend(s, 10.0) == "segment"  # 1024 > 1000
     assert autotune.decide_backend(dict(edges=100), 16.0) == "segment"
+    # a kernel that cannot fit the VMEM budget never gets the batch
+    assert autotune.decide_backend(s, 16.0, vmem_kb=0) == "segment"
 
 
 def test_retile_matches_direct_build():

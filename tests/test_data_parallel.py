@@ -267,7 +267,7 @@ def test_mesh_fit_matches_grad_accum_trainer(tiny_ds):
 def test_multi_data_axis_mesh_psum_all_axes(tiny_ds):
     """Regression: a ('pod', 'data') mesh must psum gradients over BOTH
     data axes — reducing over 'data' alone lets the 'pod' replicas silently
-    diverge (check_rep=False hides it). Parity vs grad_accum=4 pins it."""
+    diverge (check_vma=False hides it). Parity vs grad_accum=4 pins it."""
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("pod", "data"))
     assert mesh_world(mesh) == 4

@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 
-from repro.launch.hlo_analysis import analyze_hlo, xla_cost_analysis
+from repro.launch.hlo_analysis import analyze_hlo
 
 
 def _compile(f, *specs):
@@ -21,7 +21,7 @@ def test_scan_flops_multiplied():
     expect = 10 * 2 * 128 ** 3
     assert abs(res["flops"] - expect) / expect < 0.01
     # XLA's own counter is ~10x off — that's why the parser exists
-    assert xla_cost_analysis(c)["flops"] < expect / 5
+    assert c.cost_analysis()["flops"] < expect / 5
 
 
 def test_nested_scan_flops():

@@ -95,3 +95,23 @@ def test_every_output_used_exactly_once(tiny):
         b.node_ids[b.output_idx[b.output_mask]] for b in batches])
     train = tiny.splits["train"]
     assert sorted(outs.tolist()) == sorted(train.tolist())
+
+
+def test_params_readable_without_eval(tiny):
+    """With eval_every > epochs no eval runs, so no best params exist: the
+    returned params are the trained ones, never a tree whose buffers the
+    donating train step freed."""
+    import jax
+    pipe = IBMBPipeline(tiny, IBMBConfig(variant="node", k_per_output=8,
+                                         max_outputs_per_batch=64,
+                                         pad_multiple=32))
+    tr = pipe.plan("train")
+    va = pipe.plan("val", for_inference=True)
+    cfg = GNNConfig(kind="gcn", in_dim=tiny.feat_dim, hidden=32,
+                    out_dim=tiny.num_classes, num_layers=2)
+    res = GNNTrainer(cfg, lr=1e-3, seed=0).fit(
+        tr, va, tiny.num_classes, epochs=2, eval_every=5)
+    assert res.history == [] and res.best_epoch == -1
+    leaves = jax.tree_util.tree_leaves(res.params)
+    assert leaves and all(np.isfinite(np.asarray(x)).all() for x in leaves)
+    assert GNNTrainer(cfg).evaluate(res.params, va)["loss"] > 0
