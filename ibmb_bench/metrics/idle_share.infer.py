@@ -1,0 +1,9 @@
+"""Device idle share of the traced window, in %: 1 - busy / window, from
+the profiler trace (``trace_reduce``)."""
+
+
+def read(record):
+    t = record["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
