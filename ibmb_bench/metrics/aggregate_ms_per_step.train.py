@@ -1,0 +1,13 @@
+"""Device milliseconds per training step in ops under the ``aggregate``
+named scope (neighbour sums, forward and backward) in the traced window."""
+
+from ibmb_bench import program_trace
+
+
+def read(record):
+    got = program_trace.for_record(record, __file__)
+    seconds = got["scopes"].get("aggregate") if got else None
+    per = record["counters"].get("steps")
+    if seconds is None or not per:
+        return None
+    return 1e3 * seconds / per

@@ -14,6 +14,11 @@ batch/super-step t+1 from disk while step t computes holds O(budget) batch
 payload — the paper's pipelining argument, applied to graphs bigger than
 RAM.
 
+Each stage runs under an ``ibmb/loader/stage`` profiler span (args: the
+position in the order and the bytes staged) and each wait for a staged
+batch under ``ibmb/loader/wait``. The spans cost nothing measurable while
+no profiler trace is being taken.
+
 Shutdown is sentinel/Event based: a consumer that abandons the iterator
 early (break, exception, GC) triggers the generator's ``finally``, which
 sets the cancel event; the worker only ever blocks on ``q.put`` with a
@@ -36,6 +41,10 @@ _STOP = object()
 
 def device_put_batch(batch: Dict[str, np.ndarray], device=None):
     return {k: jax.device_put(v, device) for k, v in batch.items()}
+
+
+def batch_nbytes(batch: Dict[str, np.ndarray]) -> int:
+    return sum(int(v.nbytes) for v in batch.values())
 
 
 class PrefetchLoader:
@@ -120,14 +129,18 @@ class PrefetchLoader:
 
         def worker():
             try:
-                for item in self._items():
+                for k, item in enumerate(self._items()):
                     if cancel.is_set():
                         return
+                    fields = item[0] if isinstance(item, tuple) else item
+                    nbytes = batch_nbytes(fields)
+                    with jax.profiler.TraceAnnotation(
+                            "ibmb/loader/stage", batch=k, bytes=nbytes):
+                        staged = device_put_batch(fields, self.device)
                     if isinstance(item, tuple):          # (stacked, weights)
-                        item = (device_put_batch(item[0], self.device),
-                                item[1])
+                        item = (staged, item[1])
                     else:
-                        item = device_put_batch(item, self.device)
+                        item = staged
                     if not put(item):
                         return
                 put(_STOP)
@@ -140,7 +153,8 @@ class PrefetchLoader:
         t.start()
         try:
             while True:
-                item = q.get()
+                with jax.profiler.TraceAnnotation("ibmb/loader/wait"):
+                    item = q.get()
                 if item is _STOP:
                     break
                 if isinstance(item, BaseException):

@@ -235,8 +235,10 @@ class ShardedPlanExecutor:
 
         def loss_fn(params, batch, rng):
             h = gnn_apply(cfg, params, batch, rng=rng, train=rng is not None)
-            logits = output_logits(h, batch)
-            return masked_xent(logits, batch["labels"], batch["output_mask"])
+            with jax.named_scope("loss"):
+                logits = output_logits(h, batch)
+                return masked_xent(logits, batch["labels"],
+                                   batch["output_mask"])
 
         def eval_fn(params, batch):
             h = gnn_apply(cfg, params, batch, train=False)
@@ -284,9 +286,11 @@ class ShardedPlanExecutor:
                 in_specs=(P_rep, P_dp, P_dp, P_dp),
                 out_specs=(P_rep, P_dp), check_vma=False)(
                 params, batch, weights, rngs)
-            updates, opt_state = self.opt.update(
-                grads, opt_state, params, lr)
-            return apply_updates(params, updates), opt_state, losses
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.opt.update(
+                    grads, opt_state, params, lr)
+                params = apply_updates(params, updates)
+            return params, opt_state, losses
 
         @jax.jit
         def eval_superstep(params, batch, weights):

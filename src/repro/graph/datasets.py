@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.graph.csr import CSRGraph, gcn_preprocess
 from repro.graph.synthetic import DATASET_SPECS, SyntheticSpec, make_sbm_dataset
+from repro.ioutil import atomic_savez
 
 # <checkout>/.data_cache: src/repro/graph/datasets.py is four levels down
 _DEFAULT_CACHE_DIR = os.path.join(
@@ -73,9 +74,11 @@ def get_dataset(name: str, cache: bool = True) -> GraphDataset:
         ng = gcn_preprocess(g)
         ds = GraphDataset(name, g, ng, feats, labels, splits)
         if cache:
+            # published whole: a test worker that finds the file while
+            # another one writes it reads the complete file or none
             os.makedirs(os.path.dirname(path), exist_ok=True)
-            np.savez_compressed(
-                path,
+            atomic_savez(
+                path, compress=True,
                 indptr=g.indptr, indices=g.indices,
                 weights=g.weights if g.weights is not None else np.ones(g.num_edges, np.float32),
                 n_indptr=ng.indptr, n_indices=ng.indices, n_weights=ng.weights,

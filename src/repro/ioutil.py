@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 from typing import Any
 
 
@@ -39,14 +40,18 @@ def atomic_write_json(path: str, obj: Any, **dump_kwargs: Any) -> None:
     atomic_write_text(path, json.dumps(obj, **dump_kwargs))
 
 
-def atomic_savez(path: str, **arrays: Any) -> None:
-    """np.savez with the same tmp + os.replace publish."""
+def atomic_savez(path: str, *, compress: bool = False,
+                 **arrays: Any) -> None:
+    """np.savez (np.savez_compressed with ``compress``) with the same tmp +
+    os.replace publish. The temporary name is this writer's own (process
+    and thread), so processes that write one path at once (test workers
+    building the same cache file) never write into each other's file."""
     import numpy as np
 
-    tmp = path + ".tmp"
+    tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
     try:
         with open(tmp, "wb") as f:
-            np.savez(f, **arrays)
+            (np.savez_compressed if compress else np.savez)(f, **arrays)
         os.replace(tmp, path)
     except BaseException:
         try:

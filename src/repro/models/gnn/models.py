@@ -3,6 +3,13 @@
 Pure-JAX functional models: params are pytrees, apply is jit/pjit-safe, all
 shapes static. Batch format = padded induced subgraph (core.batches).
 All models use LayerNorm, ReLU and dropout per paper App. B.
+
+Device ops carry ``jax.named_scope`` paths: ``layer{l}`` around each layer
+and, inside it, ``transform`` (dense projections and bias), ``attention``
+(GAT's edge logits and softmax), ``aggregate`` (the neighbour sum on any
+backend) and ``norm`` (LayerNorm). Backward ops keep them inside
+``transpose(jvp(...))``. Scopes change op names only, never the
+computation.
 """
 from __future__ import annotations
 
@@ -90,15 +97,19 @@ def _gcn_layer(p, h, batch, backend="segment", block_f=0):
                  or (mode == "auto" and d_in < d_out))
     if agg_first:
         h = ops.weighted_agg_backend(h, batch, backend, block_f=block_f)
-        return h @ p["w"] + p["b"]
-    h = h @ p["w"]
+        with jax.named_scope("transform"):
+            return h @ p["w"] + p["b"]
+    with jax.named_scope("transform"):
+        h = h @ p["w"]
     h = ops.weighted_agg_backend(h, batch, backend, block_f=block_f)
-    return h + p["b"]
+    with jax.named_scope("transform"):
+        return h + p["b"]
 
 
 def _sage_layer(p, h, batch, backend="segment", block_f=0):
     nbr = ops.mean_agg_backend(h, batch, backend, block_f=block_f)
-    return h @ p["w_self"] + nbr @ p["w_nbr"] + p["b"]
+    with jax.named_scope("transform"):
+        return h @ p["w_self"] + nbr @ p["w_nbr"] + p["b"]
 
 
 def _gat_layer(p, h, batch, backend="segment", block_f=0):
@@ -107,17 +118,21 @@ def _gat_layer(p, h, batch, backend="segment", block_f=0):
     # (DESIGN.md §7); `backend` is accepted for a uniform layer signature.
     n = h.shape[0]
     heads, dh = p["a_src"].shape
-    z = (h @ p["w"]).reshape(n, heads, dh)
+    with jax.named_scope("transform"):
+        z = (h @ p["w"]).reshape(n, heads, dh)
     src, dst, mask = batch["edge_src"], batch["edge_dst"], batch["edge_mask"]
-    e_src = (z * p["a_src"][None]).sum(-1)   # (N, H)
-    e_dst = (z * p["a_dst"][None]).sum(-1)
-    logits = jax.nn.leaky_relu(e_src[src] + e_dst[dst], 0.2)   # (E, H)
-    att = ops.segment_softmax(logits, src, n, mask)
-    msgs = z[dst] * att[..., None]                              # (E, H, dh)
-    out = jax.ops.segment_sum(msgs, src, num_segments=n)
-    if p["b"].shape[0] == heads * dh:       # hidden layers: concat heads
-        return out.reshape(n, heads * dh) + p["b"]
-    return out.mean(axis=1) + p["b"]        # output layer: average heads
+    with jax.named_scope("attention"):
+        e_src = (z * p["a_src"][None]).sum(-1)   # (N, H)
+        e_dst = (z * p["a_dst"][None]).sum(-1)
+        logits = jax.nn.leaky_relu(e_src[src] + e_dst[dst], 0.2)   # (E, H)
+        att = ops.segment_softmax(logits, src, n, mask)
+    with jax.named_scope("aggregate"):
+        msgs = z[dst] * att[..., None]                          # (E, H, dh)
+        out = jax.ops.segment_sum(msgs, src, num_segments=n)
+    with jax.named_scope("transform"):
+        if p["b"].shape[0] == heads * dh:   # hidden layers: concat heads
+            return out.reshape(n, heads * dh) + p["b"]
+        return out.mean(axis=1) + p["b"]    # output layer: average heads
 
 
 _LAYERS = {"gcn": _gcn_layer, "sage": _sage_layer, "gat": _gat_layer}
@@ -136,13 +151,14 @@ def gnn_apply(cfg: GNNConfig, params: Dict, batch: Dict[str, jnp.ndarray],
         batch, getattr(cfg, "backend", "segment"), cfg.kind)
     block_f = int(getattr(cfg, "bcsr_block_f", 0))
     for l, p in enumerate(params["layers"]):
-        h = layer_fn(p, h, batch, backend, block_f)
-        if l < cfg.num_layers - 1:
-            h = ops.layer_norm(h, p["ln_scale"], p["ln_bias"])
-            h = jax.nn.relu(h)
-            if train and rng is not None:
-                rng, sub = jax.random.split(rng)
-                h = ops.dropout(h, cfg.dropout, sub, deterministic=False)
+        with jax.named_scope(f"layer{l}"):
+            h = layer_fn(p, h, batch, backend, block_f)
+            if l < cfg.num_layers - 1:
+                h = ops.layer_norm(h, p["ln_scale"], p["ln_bias"])
+                h = jax.nn.relu(h)
+                if train and rng is not None:
+                    rng, sub = jax.random.split(rng)
+                    h = ops.dropout(h, cfg.dropout, sub, deterministic=False)
     return h
 
 

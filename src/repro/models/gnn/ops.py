@@ -157,16 +157,17 @@ def weighted_agg_backend(h: jnp.ndarray, batch, backend: str = "segment",
     backend-equivalence test suite pins them to the segment reference.
     ``block_f`` is the tuned bcsr feature-tile width (DESIGN.md §14).
     """
-    if backend == "bcsr":
-        _require_tiles(batch)
-        return _spmm_tiles(batch["tile_cols"], batch["tile_vals"], h,
-                           block_f=block_f)
-    if backend == "dense":
-        a = _dense_adj(h.shape[0], batch["edge_src"], batch["edge_dst"],
-                       batch["edge_weight"], h.dtype)
-        return a @ h
-    return weighted_agg(h, batch["edge_src"], batch["edge_dst"],
-                        batch["edge_weight"])
+    with jax.named_scope("aggregate"):
+        if backend == "bcsr":
+            _require_tiles(batch)
+            return _spmm_tiles(batch["tile_cols"], batch["tile_vals"], h,
+                               block_f=block_f)
+        if backend == "dense":
+            a = _dense_adj(h.shape[0], batch["edge_src"], batch["edge_dst"],
+                           batch["edge_weight"], h.dtype)
+            return a @ h
+        return weighted_agg(h, batch["edge_src"], batch["edge_dst"],
+                            batch["edge_weight"])
 
 
 def mean_agg_backend(h: jnp.ndarray, batch, backend: str = "segment",
@@ -177,18 +178,19 @@ def mean_agg_backend(h: jnp.ndarray, batch, backend: str = "segment",
     graph is GCN-normalized, so every real edge has a strictly positive
     weight and ``w != 0`` equals the edge mask.
     """
-    if backend == "bcsr":
-        _require_tiles(batch)
-        bin_tiles = (batch["tile_vals"] != 0).astype(h.dtype)
-        s = _spmm_tiles(batch["tile_cols"], bin_tiles, h, block_f=block_f)
-        cnt = bin_tiles.sum(axis=(1, 3)).reshape(-1)   # (R·B,) real in-batch degree
-        return s / jnp.maximum(cnt, 1.0)[:, None]
-    if backend == "dense":
-        a = _dense_adj(h.shape[0], batch["edge_src"], batch["edge_dst"],
-                       (batch["edge_weight"] != 0), h.dtype)
-        return (a @ h) / jnp.maximum(a.sum(axis=1), 1.0)[:, None]
-    return mean_agg(h, batch["edge_src"], batch["edge_dst"],
-                    batch["edge_mask"])
+    with jax.named_scope("aggregate"):
+        if backend == "bcsr":
+            _require_tiles(batch)
+            bin_tiles = (batch["tile_vals"] != 0).astype(h.dtype)
+            s = _spmm_tiles(batch["tile_cols"], bin_tiles, h, block_f=block_f)
+            cnt = bin_tiles.sum(axis=(1, 3)).reshape(-1)   # (R·B,) real in-batch degree
+            return s / jnp.maximum(cnt, 1.0)[:, None]
+        if backend == "dense":
+            a = _dense_adj(h.shape[0], batch["edge_src"], batch["edge_dst"],
+                           (batch["edge_weight"] != 0), h.dtype)
+            return (a @ h) / jnp.maximum(a.sum(axis=1), 1.0)[:, None]
+        return mean_agg(h, batch["edge_src"], batch["edge_dst"],
+                        batch["edge_mask"])
 
 
 def segment_softmax(logits: jnp.ndarray, segment_ids: jnp.ndarray,
@@ -208,9 +210,10 @@ def segment_softmax(logits: jnp.ndarray, segment_ids: jnp.ndarray,
 
 def layer_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
                eps: float = 1e-5) -> jnp.ndarray:
-    mu = x.mean(axis=-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(axis=-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
+    with jax.named_scope("norm"):
+        mu = x.mean(axis=-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(axis=-1, keepdims=True)
+        return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
 
 
 def dropout(x: jnp.ndarray, rate: float, key, deterministic: bool) -> jnp.ndarray:

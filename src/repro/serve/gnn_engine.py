@@ -29,6 +29,12 @@ Dispatch, per query:
 Repeat traffic is served from an LRU of recent batch *outputs* — hot
 batches answer from host memory without touching the accelerator.
 
+Profiler spans (``jax.profiler.TraceAnnotation``, free while no trace is
+taken): ``ibmb/engine/route`` (routing lookup), ``ibmb/engine/forward``
+(enqueueing one batch's or super-step's transfer and forward),
+``ibmb/engine/fetch`` (device wait + copy of its logits to the host) and
+``ibmb/engine/scatter`` (rows copied into a query's answer).
+
 Dynamic graphs (DESIGN.md §10): ``swap(plan, delta)`` hot-swaps the engine
 onto a refreshed plan atomically between requests. Only the LRU entries of
 batches the refresh rebuilt or patched are invalidated; untouched batches
@@ -51,6 +57,7 @@ from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.plan import Plan
 from repro.serve.common import SystemClock
@@ -278,15 +285,24 @@ class GNNInferenceEngine:
         if len(missing) == 1 or self._ex is None:
             for bi in missing:
                 fwd = self._forward_for(*self._decisions[bi])
-                yield bi, self._lru_put(bi, np.asarray(
-                    fwd(self.params, self.plan.cache[bi])))
+                # the call enqueues the host batch's transfer and the
+                # forward; the fetch waits for both and copies back
+                with TraceAnnotation("ibmb/engine/forward", batch=bi):
+                    lg = fwd(self.params, self.plan.cache[bi])
+                with TraceAnnotation("ibmb/engine/fetch", batch=bi):
+                    lg = np.asarray(lg)
+                yield bi, self._lru_put(bi, lg)
             return
         from repro.dist.data_parallel import superstep_indices
         (idx, w), = superstep_indices(np.asarray(missing), self._ex.world)
         fns = self._ex.steps_for(
             *gnn_policy.superstep_decision(self._decisions, idx))
-        batch, _w = self._ex.stage(self.plan.cache, idx, w)
-        lg = np.asarray(fns.forward(self.params, batch))
+        first = int(idx[0])
+        with TraceAnnotation("ibmb/engine/forward", batch=first):
+            batch, _w = self._ex.stage(self.plan.cache, idx, w)
+            lg = fns.forward(self.params, batch)
+        with TraceAnnotation("ibmb/engine/fetch", batch=first):
+            lg = np.asarray(lg)
         self.stats["supersteps"] += 1
         for j in range(len(idx)):
             if w[j] > 0:
@@ -324,14 +340,17 @@ class GNNInferenceEngine:
         """Logits for `node_ids` (any output nodes covered by the plan),
         in query order. Raises KeyError for ids the plan does not cover."""
         q = np.asarray(node_ids, dtype=np.int64).ravel()
-        bidx, rows = self.plan.routing.lookup(q)
+        with TraceAnnotation("ibmb/engine/route"):
+            bidx, rows = self.plan.routing.lookup(q)
+            need = np.unique(bidx)
         self._bump(requests=1, nodes=len(q))
         out = None
-        for bi, lg in self._iter_logits(np.unique(bidx)):
-            if out is None:
-                out = np.empty((len(q), lg.shape[1]), lg.dtype)
-            sel = bidx == bi
-            out[sel] = lg[rows[sel]]
+        for bi, lg in self._iter_logits(need):
+            with TraceAnnotation("ibmb/engine/scatter", batch=int(bi)):
+                if out is None:
+                    out = np.empty((len(q), lg.shape[1]), lg.dtype)
+                sel = bidx == bi
+                out[sel] = lg[rows[sel]]
         if out is None:                              # empty query
             out = np.zeros((0, self.cfg.out_dim), np.float32)
         return out

@@ -7,6 +7,11 @@ accumulation, mini-batched evaluation with the SAME method used for training
 
 One jit'd train_step / eval_step serves every method because all batchers
 emit identical static shapes (per method).
+
+``fit`` writes profiler spans (DESIGN.md §16): ``ibmb/fit/epoch``,
+``ibmb/fit/schedule``, ``ibmb/fit/step`` (a ``StepTraceAnnotation``),
+``ibmb/fit/loss_sync`` and ``ibmb/fit/eval``; the steps' device ops carry
+the model's named scopes plus ``loss`` and ``optimizer``.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.batches import BatchCache, PaddedBatch
 from repro.core.plan import Plan
@@ -117,6 +123,9 @@ class GNNTrainer:
         self.nonfinite_policy = nonfinite_policy
         self.fault_stats = FaultStats("nonfinite_steps", "skipped_steps",
                                       "halts")
+        # steps dispatched over every fit call: the ibmb/fit/step spans'
+        # step_num
+        self.step_num = 0
         self._step_cache: Dict = {}
         base = self._steps_for(self.cfg.backend,
                                int(getattr(self.cfg, "bcsr_block_f", 0)))
@@ -143,14 +152,20 @@ class GNNTrainer:
 
         def loss_fn(params, batch, rng):
             h = gnn_apply(cfg, params, batch, rng=rng, train=True)
-            logits = output_logits(h, batch)
-            return masked_xent(logits, batch["labels"], batch["output_mask"])
+            with jax.named_scope("loss"):
+                logits = output_logits(h, batch)
+                return masked_xent(logits, batch["labels"],
+                                   batch["output_mask"])
+
+        def update(grads, opt_state, params, lr):
+            with jax.named_scope("optimizer"):
+                updates, opt_state = opt.update(grads, opt_state, params, lr)
+                return apply_updates(params, updates), opt_state
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def train_step(params, opt_state, batch, lr, rng):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
-            updates, opt_state = opt.update(grads, opt_state, params, lr)
-            params = apply_updates(params, updates)
+            params, opt_state = update(grads, opt_state, params, lr)
             return params, opt_state, loss
 
         @jax.jit
@@ -171,8 +186,7 @@ class GNNTrainer:
         def guarded_train_step(params, opt_state, batch, lr, rng):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
             ok = tree_finite(loss, grads)
-            updates, new_opt = opt.update(grads, opt_state, params, lr)
-            new_params = apply_updates(params, updates)
+            new_params, new_opt = update(grads, opt_state, params, lr)
             keep = lambda new, old: jnp.where(ok, new, old)
             return (jax.tree_util.tree_map(keep, new_params, params),
                     jax.tree_util.tree_map(keep, new_opt, opt_state),
@@ -182,14 +196,15 @@ class GNNTrainer:
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def apply_step(params, opt_state, grads, lr):
-            updates, opt_state = opt.update(grads, opt_state, params, lr)
-            return apply_updates(params, updates), opt_state
+            return update(grads, opt_state, params, lr)
 
         @jax.jit
         def eval_step(params, batch):
             h = gnn_apply(cfg, params, batch, train=False)
-            logits = output_logits(h, batch)
-            loss = masked_xent(logits, batch["labels"], batch["output_mask"])
+            with jax.named_scope("loss"):
+                logits = output_logits(h, batch)
+                loss = masked_xent(logits, batch["labels"],
+                                   batch["output_mask"])
             acc_num = (logits.argmax(-1) == batch["labels"]).astype(jnp.float32) * batch["output_mask"]
             return loss * batch["output_mask"].sum(), acc_num.sum(), batch["output_mask"].sum()
 
@@ -320,107 +335,125 @@ class GNNTrainer:
         best_params = None
         bad = 0
         epoch_times = []
-        t_total0 = time.time()
+        t_total0 = time.perf_counter()
 
         for ep in range(epochs):
-            t0 = time.time()
-            if not fixed:  # resampling baselines pay regeneration every epoch
-                epoch_pb = train_batches.epoch_batches(ep)
-                host = as_host_batches(epoch_pb)
-                order = np.random.default_rng(self.seed + ep).permutation(len(host))
-                decisions = gnn_policy.batch_decisions(
-                    epoch_pb, self.policy, self.cfg)
-            else:
-                order = order_fn(ep)
-            ep_loss = 0.0
-            nsteps = 0
-            if executor is not None:
-                # one shard_map super-step per `world` batches; micro-batch
-                # j of super-step s is global step s*world+j, so its dropout
-                # key matches the single-device loop's step counter exactly.
-                # The loader groups with the SAME superstep_indices the
-                # executor uses, so groups[si] names super-step si's batches
-                # and its (backend, block_f) executable (§14).
-                groups = executor.supersteps(order)
-                loader = PrefetchLoader(
-                    host, order, group=executor.world,
-                    device=executor.batch_sharding)
-                for si, (batch, w) in enumerate(loader):
-                    fns = executor.steps_for(*gnn_policy.superstep_decision(
-                        decisions, groups[si][0]))
-                    keys = jnp.stack(
-                        [step_rng(base_rng, ep, si * executor.world + j)
-                         for j in range(executor.world)])
-                    params, opt_state, losses = fns.train(
-                        params, opt_state, batch, w,
-                        jnp.float32(self.sched.lr), keys)
-                    real = np.asarray(w) > 0
-                    ep_loss += float(np.asarray(losses)[real].sum())
-                    nsteps += int(real.sum())
-            else:
-                loader = PrefetchLoader(host, order)
-                for bi, batch in enumerate(loader):
-                    # loader position bi holds batch order[bi]; its stored
-                    # decision picks the executable (uniform when fixed)
-                    steps = self._steps_for(*decisions[int(order[bi])])
-                    sub = step_rng(base_rng, ep, bi)
-                    if self.grad_accum == 1:
-                        if self.nonfinite_policy == "off":
-                            params, opt_state, loss = steps["train"](
-                                params, opt_state, batch,
-                                jnp.float32(self.sched.lr), sub)
-                        else:
-                            params, opt_state, loss, ok = steps["guarded"](
-                                params, opt_state, batch,
-                                jnp.float32(self.sched.lr), sub)
-                            if not bool(ok):
-                                self._on_nonfinite(ep, bi)
-                                continue   # loss is poisoned; update held
-                    else:
-                        loss, grads = steps["grad"](params, batch, sub)
-                        if self.nonfinite_policy != "off" and \
-                                not bool(self._finite_check(loss, grads)):
-                            # never let a NaN enter the accumulator: one bad
-                            # micro-batch would poison the whole macro-step
-                            self._on_nonfinite(ep, bi)
-                            continue
-                        g = accum.add(grads)
+            with TraceAnnotation("ibmb/fit/epoch", epoch=ep):
+                t0 = time.perf_counter()
+                if not fixed:  # resampling baselines pay regeneration every epoch
+                    epoch_pb = train_batches.epoch_batches(ep)
+                    host = as_host_batches(epoch_pb)
+                    order = np.random.default_rng(self.seed + ep).permutation(len(host))
+                    decisions = gnn_policy.batch_decisions(
+                        epoch_pb, self.policy, self.cfg)
+                else:
+                    with TraceAnnotation("ibmb/fit/schedule"):
+                        order = order_fn(ep)
+                ep_loss = 0.0
+                nsteps = 0
+                if executor is not None:
+                    # one shard_map super-step per `world` batches; micro-batch
+                    # j of super-step s is global step s*world+j, so its dropout
+                    # key matches the single-device loop's step counter exactly.
+                    # The loader groups with the SAME superstep_indices the
+                    # executor uses, so groups[si] names super-step si's batches
+                    # and its (backend, block_f) executable (§14).
+                    groups = executor.supersteps(order)
+                    loader = PrefetchLoader(
+                        host, order, group=executor.world,
+                        device=executor.batch_sharding)
+                    for si, (batch, w) in enumerate(loader):
+                        with StepTraceAnnotation("ibmb/fit/step",
+                                                 step_num=self.step_num):
+                            self.step_num += 1
+                            fns = executor.steps_for(*gnn_policy.superstep_decision(
+                                decisions, groups[si][0]))
+                            keys = jnp.stack(
+                                [step_rng(base_rng, ep, si * executor.world + j)
+                                 for j in range(executor.world)])
+                            params, opt_state, losses = fns.train(
+                                params, opt_state, batch, w,
+                                jnp.float32(self.sched.lr), keys)
+                            real = np.asarray(w) > 0
+                            with TraceAnnotation("ibmb/fit/loss_sync"):
+                                ep_loss += float(np.asarray(losses)[real].sum())
+                            nsteps += int(real.sum())
+                else:
+                    loader = PrefetchLoader(host, order)
+                    for bi, batch in enumerate(loader):
+                        with StepTraceAnnotation("ibmb/fit/step",
+                                                 step_num=self.step_num):
+                            self.step_num += 1
+                            # loader position bi holds batch order[bi]; its stored
+                            # decision picks the executable (uniform when fixed)
+                            steps = self._steps_for(*decisions[int(order[bi])])
+                            sub = step_rng(base_rng, ep, bi)
+                            if self.grad_accum == 1:
+                                if self.nonfinite_policy == "off":
+                                    params, opt_state, loss = steps["train"](
+                                        params, opt_state, batch,
+                                        jnp.float32(self.sched.lr), sub)
+                                else:
+                                    params, opt_state, loss, ok = steps["guarded"](
+                                        params, opt_state, batch,
+                                        jnp.float32(self.sched.lr), sub)
+                                    with TraceAnnotation("ibmb/fit/loss_sync"):
+                                        finite = bool(ok)
+                                    if not finite:
+                                        self._on_nonfinite(ep, bi)
+                                        continue   # loss is poisoned; update held
+                            else:
+                                loss, grads = steps["grad"](params, batch, sub)
+                                if self.nonfinite_policy != "off":
+                                    with TraceAnnotation("ibmb/fit/loss_sync"):
+                                        finite = bool(
+                                            self._finite_check(loss, grads))
+                                    if not finite:
+                                        # never let a NaN enter the accumulator:
+                                        # one bad micro-batch would poison the
+                                        # whole macro-step
+                                        self._on_nonfinite(ep, bi)
+                                        continue
+                                g = accum.add(grads)
+                                if g is not None:
+                                    params, opt_state = self._apply_step(
+                                        params, opt_state, g, jnp.float32(self.sched.lr))
+                            with TraceAnnotation("ibmb/fit/loss_sync"):
+                                ep_loss += float(loss)
+                            nsteps += 1
+                    if self.grad_accum > 1:
+                        g = accum.flush()
                         if g is not None:
                             params, opt_state = self._apply_step(
                                 params, opt_state, g, jnp.float32(self.sched.lr))
-                    ep_loss += float(loss)
-                    nsteps += 1
-                if self.grad_accum > 1:
-                    g = accum.flush()
-                    if g is not None:
-                        params, opt_state = self._apply_step(
-                            params, opt_state, g, jnp.float32(self.sched.lr))
-            epoch_times.append(time.time() - t0)
+                epoch_times.append(time.perf_counter() - t0)
 
-            if (ep + 1) % eval_every == 0:
-                val = executor.evaluate(params, val_host,
-                                        decisions=val_decisions) \
-                    if executor is not None \
-                    else self.evaluate(params, val_batches)
-                self.sched.step(val["loss"])
-                history.append({"epoch": ep, "train_loss": ep_loss / max(nsteps, 1),
-                                "val_loss": val["loss"], "val_acc": val["acc"],
-                                "lr": self.sched.lr,
-                                "time": time.time() - t_total0})
-                if verbose:
-                    print(f"  ep {ep:4d} loss {ep_loss/max(nsteps,1):.4f} "
-                          f"val_loss {val['loss']:.4f} val_acc {val['acc']:.4f} lr {self.sched.lr:.2e}")
-                if val["loss"] < best_val_loss - 1e-6:
-                    best_val_loss, best_val_acc, best_epoch = val["loss"], val["acc"], ep
-                    best_params = jax.tree_util.tree_map(lambda x: x.copy(), params)
-                    bad = 0
-                else:
-                    bad += 1
-                    if bad >= self.early_stop_patience:
-                        break
+                if (ep + 1) % eval_every == 0:
+                    with TraceAnnotation("ibmb/fit/eval"):
+                        val = executor.evaluate(params, val_host,
+                                                decisions=val_decisions) \
+                            if executor is not None \
+                            else self.evaluate(params, val_batches)
+                    self.sched.step(val["loss"])
+                    history.append({"epoch": ep, "train_loss": ep_loss / max(nsteps, 1),
+                                    "val_loss": val["loss"], "val_acc": val["acc"],
+                                    "lr": self.sched.lr,
+                                    "time": time.perf_counter() - t_total0})
+                    if verbose:
+                        print(f"  ep {ep:4d} loss {ep_loss/max(nsteps,1):.4f} "
+                              f"val_loss {val['loss']:.4f} val_acc {val['acc']:.4f} lr {self.sched.lr:.2e}")
+                    if val["loss"] < best_val_loss - 1e-6:
+                        best_val_loss, best_val_acc, best_epoch = val["loss"], val["acc"], ep
+                        best_params = jax.tree_util.tree_map(lambda x: x.copy(), params)
+                        bad = 0
+                    else:
+                        bad += 1
+                        if bad >= self.early_stop_patience:
+                            break
         return TrainResult(
             params=params if best_params is None else best_params,
             history=history, best_val_acc=best_val_acc,
             best_epoch=best_epoch,
             time_per_epoch=float(np.mean(epoch_times)) if epoch_times else 0.0,
-            preprocess_time=preprocess_time, total_time=time.time() - t_total0)
+            preprocess_time=preprocess_time,
+            total_time=time.perf_counter() - t_total0)
