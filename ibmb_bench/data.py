@@ -12,7 +12,7 @@ import os
 
 import numpy as np
 
-from ibmb_bench import graphgen
+from ibmb_bench import graphgen, reference
 from ibmb_bench.reference import graph as ref_graph
 
 
@@ -43,11 +43,10 @@ def ibmb_config(ctx):
 
 
 def model_config(ctx):
+    """The program's model of the configuration's every ``model`` field; a
+    field the program lacks raises a ``TypeError`` that names it."""
     from repro.models.gnn.models import GNNConfig
-    m = ctx.model
-    return GNNConfig(kind=m["kind"], in_dim=m["in_dim"], hidden=m["hidden"],
-                     out_dim=m["out_dim"], num_layers=m["num_layers"],
-                     heads=m["heads"], dropout=m["dropout"], dtype=m["dtype"])
+    return GNNConfig(**ctx.model)
 
 
 def policy(ctx):
@@ -97,8 +96,9 @@ def reference_batch(g: graphgen.GraphData, ng: ref_graph.NormGraph, plan,
     return ref_graph.build_batch(ng, g.features, g.labels, ids, outs)
 
 
-def device_weights(ref_module, model: dict, key, random_affine: bool):
-    """Weights made on the device in one jitted call from ``key``."""
+def device_weights(model: dict, key, random_affine: bool):
+    """Weights of the model's reference, made on the device in one jitted
+    call from ``key``."""
     import jax
-    return jax.jit(lambda k: ref_module.init_params(model, k, random_affine)
-                   )(key)
+    ref = reference.for_model(model)
+    return jax.jit(lambda k: ref.init_params(model, k, random_affine))(key)

@@ -16,7 +16,7 @@ from typing import Dict
 import jax.numpy as jnp
 import numpy as np
 
-from ibmb_bench import data
+from ibmb_bench import data, reference
 from ibmb_bench.reference import common, graph as ref_graph
 
 
@@ -42,8 +42,7 @@ def reference_rows(model: dict, params, g, plan, batches,
                    dtype=common.F32) -> Dict[int, np.ndarray]:
     """``{global output id: reference logits}`` over ``batches`` (the first
     batch that holds an output answers for it, as the plan routes)."""
-    from ibmb_bench.reference import gat, gcn
-    ref = {"gcn": gcn, "gat": gat}[model["kind"]]
+    ref = reference.for_model(model)
     ng = ref_graph.normalized_graph(g.src, g.dst, g.num_nodes)
     fwd = common.jitted(_forward, ref.forward, tuple(sorted(model.items())),
                         dtype)

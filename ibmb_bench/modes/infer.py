@@ -14,7 +14,6 @@ import time
 import numpy as np
 
 from ibmb_bench import data, flops, harness, logits_check
-from ibmb_bench.reference import gat, gcn
 
 
 def load(ctx):
@@ -26,8 +25,7 @@ def load(ctx):
 
 def engine_for(ctx, plan, seed: int, **engine_kw):
     """Weights of ``seed`` made on the device, and an engine over them."""
-    ref = {"gcn": gcn, "gat": gat}[ctx.model["kind"]]
-    params = data.device_weights(ref, ctx.model, harness.seed_key(seed),
+    params = data.device_weights(ctx.model, harness.seed_key(seed),
                                  random_affine=True)
     from repro.serve import GNNInferenceEngine
     engine = GNNInferenceEngine(plan, data.model_config(ctx), params,

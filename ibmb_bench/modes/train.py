@@ -20,7 +20,7 @@ import time
 import jax
 import numpy as np
 
-from ibmb_bench import data, flops, harness
+from ibmb_bench import data, flops, harness, reference
 from ibmb_bench.reference import common, graph as ref_graph
 
 NO_EVAL = 1 << 30          # eval_every past the last epoch: no evaluation
@@ -165,8 +165,7 @@ def compare(model, tap, plan, g, key, lr, candidate=None, half=False):
     that precision takes the program's place, as the control does; with
     ``half`` as well, its loss leaves out half of each batch's outputs (a
     planted fault)."""
-    from ibmb_bench.reference import gcn, gat
-    ref = {"gcn": gcn, "gat": gat}[model["kind"]]
+    ref = reference.for_model(model)
     ng = ref_graph.normalized_graph(g.src, g.dst, g.num_nodes)
     idx = [_batch_index(plan, s) for s in tap.staged]
     if min(idx) < 0 or len(set(idx)) < len(idx):

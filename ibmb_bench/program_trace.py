@@ -13,7 +13,9 @@ attention``, ...). This module reads both from the same trace that
   averaged over the devices used. An op's scope is the last component of
   its scope path that names one of ``SCOPES``; a fused op carries its
   root's path, which XLA keeps in the op's metadata (the ``tf_op`` stat,
-  shown by TensorBoard as the op's framework name scope);
+  shown by TensorBoard as the op's framework name scope). A reader of a
+  scope that ``SCOPES`` does not name asks ``for_record`` for it, which
+  splits the device seconds per scope path that the summary keeps;
 * the device's idle seconds whose gap lies inside some ``ibmb/`` span.
 
 A trace of a program that has no such spans or scopes gives empty spans
@@ -53,11 +55,11 @@ class Trace:
     op_paths: Dict[str, str]
 
 
-def scope_of(path: str) -> str:
-    """The innermost component of ``path`` that names a scope of
-    ``SCOPES``, looking through ``jvp(...)`` and ``transpose(...)``."""
+def scope_of(path: str, names=SCOPES) -> str:
+    """The innermost component of ``path`` that names a scope of ``names``,
+    looking through ``jvp(...)`` and ``transpose(...)``."""
     for part in reversed(_SPLIT.split(path or "")):
-        if part in SCOPES:
+        if part in names:
             return part
     return NO_SCOPE
 
@@ -208,6 +210,24 @@ def scope_seconds(trace: Trace, n_devices: int, lo: float, hi: float,
     return dict(out)
 
 
+def path_seconds(trace: Trace, ops: Dict[str, float]) -> Dict[str, float]:
+    """Device seconds per scope path (``""`` for ops with none), from
+    ``op_seconds``'s result."""
+    out: Dict[str, float] = defaultdict(float)
+    for name, s in ops.items():
+        out[trace.op_paths.get(name, "")] += s
+    return dict(out)
+
+
+def scopes_by_path(paths: Dict[str, float], names) -> Dict[str, float]:
+    """Device seconds per scope of ``names`` (or ``(none)``), from
+    ``path_seconds``'s result: each path goes to its innermost name."""
+    out: Dict[str, float] = defaultdict(float)
+    for path, s in paths.items():
+        out[scope_of(path, names)] += s
+    return dict(out)
+
+
 def idle_in_spans(trace: Trace, lo: float, hi: float) -> float:
     """Idle seconds of the first device whose gap's midpoint lies inside
     some ``ibmb/`` span: the idle time the program's spans account for."""
@@ -233,9 +253,9 @@ def idle_in_spans(trace: Trace, lo: float, hi: float) -> float:
 def summarize(path: str, n_devices: int) -> Dict:
     """Of the trace at ``path``, inside the window that ``trace_reduce``
     uses: ``spans`` (the ``ibmb/`` spans), ``scopes``, ``idle_in_spans_s``,
-    ``harness_spans`` (the ``bench/`` spans, as ``spans``) and
+    ``harness_spans`` (the ``bench/`` spans, as ``spans``),
     ``unscoped_ops``, the ops with the most device time that carry no
-    known scope, as [name, seconds] pairs."""
+    known scope, as [name, seconds] pairs, and ``path_seconds``."""
     trace = load(path)
     lo, hi = tr.window_of(trace.planes)
     ops = op_seconds(trace, n_devices, lo, hi)
@@ -249,10 +269,12 @@ def summarize(path: str, n_devices: int) -> Dict:
                 idle_in_spans_s=idle_in_spans(trace, lo, hi),
                 harness_spans=span_totals(trace, lo, hi, harness),
                 unscoped_ops=[[n[:tr.NAME_CHARS], s]
-                              for n, s in unscoped[:UNSCOPED_TOP]])
+                              for n, s in unscoped[:UNSCOPED_TOP]],
+                path_seconds=path_seconds(trace, ops))
 
 
-def for_record(record: Dict, metric_file: str) -> Optional[Dict]:
+def for_record(record: Dict, metric_file: str, scopes=()
+               ) -> Optional[Dict]:
     """The program's spans and scopes of the traced run that ``record``
     describes: the newest trace under the checkout's
     ``ibmb_bench/.cache/traces/`` (the harness writes one per traced run,
@@ -262,7 +284,12 @@ def for_record(record: Dict, metric_file: str) -> Optional[Dict]:
     where the trace cannot be read or its window is not the record's.
     ``metric_file`` is a reader's ``__file__``, which names the checkout.
     The summary is kept in the record, so the readers of one run read the
-    trace once."""
+    trace once.
+
+    ``scopes`` names scopes beyond ``SCOPES``: the result's ``scopes`` then
+    splits device time over ``SCOPES`` and those names together, each op to
+    the innermost of them on its path, so a new name inside a known scope
+    takes its ops from that scope. The record's summary stays as it is."""
     t = record.get("trace")
     if not t:
         return None
@@ -278,7 +305,11 @@ def for_record(record: Dict, metric_file: str) -> Optional[Dict]:
                 > 1e-6 * max(t["window_s"], 1e-9):
             raise ValueError(f"{path}: window {got['window_s']} s, the "
                              f"run's {t['window_s']} s")
-        if not got["spans"] and set(got["scopes"]) <= {NO_SCOPE}:
-            got = None
         record["program_trace"] = got
-    return record["program_trace"]
+    got = record["program_trace"]
+    if scopes:
+        got = dict(got, scopes=scopes_by_path(got["path_seconds"],
+                                              SCOPES + tuple(scopes)))
+    if not got["spans"] and set(got["scopes"]) <= {NO_SCOPE}:
+        return None
+    return got

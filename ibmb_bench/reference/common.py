@@ -23,6 +23,12 @@ def matmul(a, b, dtype):
     return jnp.matmul(a, b, precision=precision(dtype))
 
 
+def dims(model: dict):
+    """Input width, then each layer's output width."""
+    return ([model["in_dim"]] + [model["hidden"]] * (model["num_layers"] - 1)
+            + [model["out_dim"]])
+
+
 def glorot(key, shape):
     lim = float(np.sqrt(6.0 / (shape[0] + shape[-1])))
     return jax.random.uniform(key, shape, F32, -lim, lim)

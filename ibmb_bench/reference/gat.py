@@ -14,17 +14,15 @@ import jax.numpy as jnp
 
 from ibmb_bench.reference import common
 
-
-def _dims(model: dict):
-    return ([model["in_dim"]] + [model["hidden"]] * (model["num_layers"] - 1)
-            + [model["out_dim"]])
+SMALL = dict(kind="gat", in_dim=16, hidden=32, out_dim=5, num_layers=3,
+             heads=4, dropout=0.3, dtype="float32")
 
 
 def init_params(model: dict, key, random_affine: bool = False):
     """Glorot-uniform ``w``, ``a_src``, ``a_dst`` (sub-keys 0-2 of a 6-way
     split per layer), zero biases, unit LayerNorm; ``random_affine`` draws
     biases and LayerNorm parameters too, for served weights."""
-    dims, heads = _dims(model), model["heads"]
+    dims, heads = common.dims(model), model["heads"]
     layers = []
     for l in range(model["num_layers"]):
         key, *ks = jax.random.split(key, 6)
@@ -81,3 +79,19 @@ def forward(model: dict, params, batch, dtype=common.F32, key=None):
 def loss(model: dict, params, batch, dtype=common.F32, key=None):
     logits = forward(model, params, batch, dtype, key)
     return common.cross_entropy(logits[batch["out"]], batch["labels"])
+
+
+def forward_flops(model: dict, n: int, e: int) -> int:
+    """Per layer with input width ``d_in`` and ``H`` heads of width ``d``,
+    ``w = H·d``: ``2·n·d_in·w`` for the transform, ``4·n·w`` for the two
+    attention products with ``a_src`` and ``a_dst``, and ``2·e·w`` for the
+    attention-weighted sum over edges. The output layer's ``w`` is heads
+    times classes: it averages its heads after the sum."""
+    dims = common.dims(model)
+    total = 0
+    for l in range(model["num_layers"]):
+        d_in, w = dims[l], dims[l + 1]
+        if l == model["num_layers"] - 1:
+            w = model["heads"] * w
+        total += 2 * n * d_in * w + 4 * n * w + 2 * e * w
+    return int(total)

@@ -12,14 +12,16 @@ import jax.numpy as jnp
 
 from ibmb_bench.reference import common
 
+SMALL = dict(kind="gcn", in_dim=16, hidden=32, out_dim=5, num_layers=3,
+             heads=4, dropout=0.3, dtype="float32")
+
 
 def init_params(model: dict, key, random_affine: bool = False):
     """Glorot-uniform weights drawn from ``key`` as the program draws them
     (one 6-way split per layer, the weight from the first sub-key), zero
     biases, unit LayerNorm. ``random_affine`` draws biases and LayerNorm
     parameters too, for served weights."""
-    dims = ([model["in_dim"]] + [model["hidden"]] * (model["num_layers"] - 1)
-            + [model["out_dim"]])
+    dims = common.dims(model)
     layers = []
     for l in range(model["num_layers"]):
         key, *ks = jax.random.split(key, 6)
@@ -63,3 +65,15 @@ def forward(model: dict, params, batch, dtype=common.F32, key=None):
 def loss(model: dict, params, batch, dtype=common.F32, key=None):
     logits = forward(model, params, batch, dtype, key)
     return common.cross_entropy(logits[batch["out"]], batch["labels"])
+
+
+def forward_flops(model: dict, n: int, e: int) -> int:
+    """Per layer with input width ``d_in`` and output width ``d_out``:
+    ``2·n·d_in·d_out`` for the transform and ``2·e·d_out`` for the
+    weighted aggregation, which runs at the output width."""
+    dims = common.dims(model)
+    total = 0
+    for l in range(model["num_layers"]):
+        d_in, d_out = dims[l], dims[l + 1]
+        total += 2 * n * d_in * d_out + 2 * e * d_out
+    return int(total)

@@ -28,6 +28,7 @@ def report(trace_dir: str, n_devices: int = 1) -> dict:
                                   n_devices)
     idle = base["window_s"] - base["busy_s"]
     inside = got.pop("idle_in_spans_s")
+    del got["path_seconds"]
     return dict(
         busy_s=base["busy_s"], idle_s=idle, idle_in_ibmb_spans_s=inside,
         idle_in_ibmb_spans_share=inside / idle if idle > 0 else None,

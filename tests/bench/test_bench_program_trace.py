@@ -237,3 +237,53 @@ def test_recorded_tpu_trace(tmp_path):
     assert sum(s for _, s in got["unscoped_ops"]) == pytest.approx(
         scopes.get(pt.NO_SCOPE, 0.0))
     assert got["harness_spans"] == {}      # taken without the harness
+
+
+def test_a_reader_can_ask_for_a_scope_not_in_scopes(tmp_path):
+    """A new reader file asks ``for_record`` for a name beyond ``SCOPES``
+    and reads it; the readers that ask for nothing read as before."""
+    bdir = _checkout(tmp_path, SYNTH)
+    (bdir / "metrics" / "exp_ms_per_batch.new.py").write_text(
+        "from ibmb_bench import program_trace\n\n\n"
+        "def read(record):\n"
+        "    got = program_trace.for_record(record, __file__,\n"
+        "                                   scopes=('exp',))\n"
+        "    seconds = got['scopes'].get('exp') if got else None\n"
+        "    return None if seconds is None else \\\n"
+        "        1e3 * seconds / record['counters']['batches']\n")
+    old = {name: harness.load_module(str(bdir / "metrics" / (name + ".py")))
+           for name in EXPECTED}
+    alone = {name: r.read(_record()) for name, r in old.items()}
+    record = _record()
+    new = harness.load_module(str(bdir / "metrics" /
+                                  "exp_ms_per_batch.new.py"))
+    assert new.read(record) == pytest.approx(6e-3 / 2)
+    assert {name: r.read(record) for name, r in old.items()} == alone
+    assert pt.for_record(record, str(bdir / "metrics" / "x.py"))[
+        "scopes"] == pytest.approx({"aggregate": 12e-6, "attention": 6e-6,
+                                    pt.NO_SCOPE: 2e-6})
+    assert new.read(dict(_record(), trace=None)) is None
+    bare = _checkout(tmp_path / "bare", BARE)
+    assert pt.for_record(_record(), str(bare / "metrics" / "x.py"),
+                         scopes=("exp",)) is None
+
+
+def test_recorded_tpu_trace_reads_a_new_scope_name(tmp_path):
+    """On the trace from one v5e: ``scatter-add``, a name inside the
+    ``aggregate`` scope, takes the aggregation's scatters from it, and the
+    scopes still add up to the same device time."""
+    bdir = tmp_path / "ibmb_bench"
+    tdir = bdir / ".cache" / "traces" / "cell"
+    tdir.mkdir(parents=True)
+    shutil.copy(TPU_TRACE, tdir / "t.xplane.pb")
+    trace = pt.load(TPU_TRACE)
+    lo, hi = tr.window_of(trace.planes)
+    scopes = pt.scope_seconds(trace, 1, lo, hi)
+    record = dict(trace=dict(window_s=(hi - lo) / 1e9, devices=1))
+    x = str(bdir / "metrics" / "x.py")
+    got = pt.for_record(record, x, scopes=("scatter-add",))["scopes"]
+    assert got["scatter-add"] > 0.95 * scopes["aggregate"]
+    assert got["aggregate"] == pytest.approx(
+        scopes["aggregate"] - got["scatter-add"])
+    assert sum(got.values()) == pytest.approx(sum(scopes.values()))
+    assert pt.for_record(record, x)["scopes"] == scopes

@@ -1,6 +1,8 @@
 """The plain float32 references against the program, at a tiny size on the
 CPU: the forward (``gnn_apply``) with and without dropout, the loss
-(``masked_xent``) and its gradient, on batches of a real plan.
+(``masked_xent``) and its gradient, on batches of a real plan, and the
+initial weights. Every kind that ``reference.kinds()`` finds is tested,
+with its module's ``SMALL`` model fitted to the test graph's widths.
 
 Tolerances: both sides run float32 with full-precision products on the CPU
 and differ only in summation order (the reference sums over its own edge
@@ -16,8 +18,8 @@ import bench_helpers  # noqa: F401  (puts the repo on sys.path)
 import jax
 import jax.numpy as jnp
 
-from ibmb_bench import graphgen
-from ibmb_bench.reference import common, gat, gcn, graph as ref_graph
+from ibmb_bench import graphgen, reference
+from ibmb_bench.reference import common, graph as ref_graph
 
 LOGIT_TOL = 1e-5
 GRAD_TOL = 1e-4
@@ -43,15 +45,21 @@ def setup():
     return g, plan, ng
 
 
+KINDS = reference.kinds()
+
+
+def _ref(kind):
+    return reference.for_model({"kind": kind})
+
+
 def _model(kind):
-    return dict(kind=kind, in_dim=16, hidden=32, out_dim=5, num_layers=3,
-                heads=4, dropout=0.3, dtype="float32")
+    return dict(_ref(kind).SMALL, in_dim=SPEC["feat_dim"],
+                out_dim=SPEC["num_classes"])
 
 
 def _program(kind):
     from repro.models.gnn.models import GNNConfig
-    return GNNConfig(kind=kind, in_dim=16, hidden=32, out_dim=5,
-                     num_layers=3, heads=4, dropout=0.3)
+    return GNNConfig(**_model(kind))
 
 
 def _pair(setup, b):
@@ -60,11 +68,11 @@ def _pair(setup, b):
     return plan.cache[b], data.reference_batch(g, ng, plan, b)
 
 
-@pytest.mark.parametrize("kind", ["gcn", "gat"])
+@pytest.mark.parametrize("kind", KINDS)
 @pytest.mark.parametrize("train", [False, True])
 def test_forward_matches_gnn_apply(setup, kind, train):
     from repro.models.gnn.models import gnn_apply, output_logits
-    ref = {"gcn": gcn, "gat": gat}[kind]
+    ref = _ref(kind)
     params = ref.init_params(_model(kind), jax.random.PRNGKey(3),
                              random_affine=True)
     key = common.dropout_key(jax.random.PRNGKey(9), 2, 1) if train else None
@@ -79,10 +87,10 @@ def test_forward_matches_gnn_apply(setup, kind, train):
         assert np.abs(got - want).max() <= LOGIT_TOL * scale
 
 
-@pytest.mark.parametrize("kind", ["gcn", "gat"])
+@pytest.mark.parametrize("kind", KINDS)
 def test_loss_and_gradient_match(setup, kind):
     from repro.models.gnn.models import gnn_apply, masked_xent, output_logits
-    ref = {"gcn": gcn, "gat": gat}[kind]
+    ref = _ref(kind)
     params = ref.init_params(_model(kind), jax.random.PRNGKey(4),
                              random_affine=True)
     key = common.dropout_key(jax.random.PRNGKey(1), 0, 0)
@@ -108,17 +116,19 @@ def test_loss_and_gradient_match(setup, kind):
         assert np.linalg.norm(a - b) / nb <= GRAD_TOL
 
 
-def test_program_init_is_mirrored():
+@pytest.mark.parametrize("kind", KINDS)
+def test_program_init_is_mirrored(kind):
     """The training reference draws the program's initial weights from the
     same key (``fit`` initialises from ``fold_in(rng, 0)``)."""
     from repro.models.gnn.models import init_gnn
-    for kind in ("gcn", "gat"):
-        key = jax.random.PRNGKey(21)
-        want = init_gnn(_program(kind), key)
-        got = {"gcn": gcn, "gat": gat}[kind].init_params(_model(kind), key)
-        for a, b in zip(jax.tree_util.tree_leaves(got),
-                        jax.tree_util.tree_leaves(want)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    key = jax.random.PRNGKey(21)
+    want = init_gnn(_program(kind), key)
+    got = _ref(kind).init_params(_model(kind), key)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_normalization_matches_gcn_preprocess():
