@@ -119,20 +119,25 @@ def _gat_layer(p, h, batch, backend="segment", block_f=0):
     n = h.shape[0]
     heads, dh = p["a_src"].shape
     with jax.named_scope("transform"):
-        z = (h @ p["w"]).reshape(n, heads, dh)
+        zf = h @ p["w"]                          # (N, H·dh)
+        z = zf.reshape(n, heads, dh)
     src, dst, mask = batch["edge_src"], batch["edge_dst"], batch["edge_mask"]
     with jax.named_scope("attention"):
         e_src = (z * p["a_src"][None]).sum(-1)   # (N, H)
         e_dst = (z * p["a_dst"][None]).sum(-1)
         logits = jax.nn.leaky_relu(e_src[src] + e_dst[dst], 0.2)   # (E, H)
         att = ops.segment_softmax(logits, src, n, mask)
+    # Each edge scatters one flat row, never a (H, dh) slab: a TPU
+    # scatter-add costs per (sublane, 128-lane) tile an update spans, and a
+    # slab takes a tile row per head (DESIGN.md §7).
     with jax.named_scope("aggregate"):
-        msgs = z[dst] * att[..., None]                          # (E, H, dh)
+        if p["b"].shape[0] == heads * dh:   # hidden layers: concat heads
+            msgs = zf[dst] * jnp.repeat(att, dh, axis=1)        # (E, H·dh)
+        else:                               # output layer: average heads
+            msgs = (z[dst] * att[..., None]).mean(axis=1)       # (E, C)
         out = jax.ops.segment_sum(msgs, src, num_segments=n)
     with jax.named_scope("transform"):
-        if p["b"].shape[0] == heads * dh:   # hidden layers: concat heads
-            return out.reshape(n, heads * dh) + p["b"]
-        return out.mean(axis=1) + p["b"]    # output layer: average heads
+        return out + p["b"]
 
 
 _LAYERS = {"gcn": _gcn_layer, "sage": _sage_layer, "gat": _gat_layer}
