@@ -3,8 +3,8 @@
 ``repro.analysis`` mechanically enforces the contracts the rest of the
 codebase states in prose: deterministic plan builds (§8/§14), lock
 discipline in the threaded serving tier (§11–§12), atomic artifact
-writes (§12–§13), a single canonical fault-point registry (§12),
-jit-executable cache hygiene (§14), and bench-gate/emitter agreement.
+writes (§12–§13), a single canonical fault-point registry (§12) and
+jit-executable cache hygiene (§14).
 
 Run it with ``python -m repro.analysis [--format json] [paths]``; see
 ``repro.analysis.cli``. The package is stdlib-only (``ast`` + ``re`` +
@@ -17,7 +17,6 @@ from repro.analysis.locks import LockDisciplineChecker
 from repro.analysis.atomic_write import AtomicWriteChecker
 from repro.analysis.fault_points import FaultPointChecker
 from repro.analysis.jit_cache import JitCacheChecker
-from repro.analysis.bench_gate import BenchGateChecker
 
 ALL_CHECKERS = (
     DeterminismChecker,
@@ -25,13 +24,11 @@ ALL_CHECKERS = (
     AtomicWriteChecker,
     FaultPointChecker,
     JitCacheChecker,
-    BenchGateChecker,
 )
 
 __all__ = [
     "ALL_CHECKERS",
     "AtomicWriteChecker",
-    "BenchGateChecker",
     "Checker",
     "DeterminismChecker",
     "FaultPointChecker",

@@ -1,10 +1,9 @@
 """Atomic-write checker (DESIGN.md §12/§13/§15).
 
-Every persisted artifact — plans, plan stores, checkpoints, bench
-trajectory JSONs — must be published with the tmp + ``os.replace``
-idiom (``repro.ioutil``): readers see the old file or the new one,
-never a truncated in-between, and a crash mid-write leaves no commit
-point behind.
+Every persisted artifact — plans, plan stores, checkpoints — must be
+published with the tmp + ``os.replace`` idiom (``repro.ioutil``):
+readers see the old file or the new one, never a truncated in-between,
+and a crash mid-write leaves no commit point behind.
 
 The rule flags write-mode ``open()`` calls in artifact-producing scopes
 unless the write demonstrably flows through the idiom: the enclosing
@@ -22,7 +21,7 @@ from repro.analysis.model import Checker, Finding, Module, Project, call_name
 
 RULE = "atomic-write"
 
-SCOPE_PREFIXES = ("src/repro/checkpoint/", "src/repro/ooc/", "benchmarks/")
+SCOPE_PREFIXES = ("src/repro/checkpoint/", "src/repro/ooc/")
 SCOPE_FILES = ("src/repro/core/plan.py",)
 
 _PUBLISH_CALLS = {"os.replace", "os.rename"}

@@ -131,7 +131,7 @@ class FaultPointChecker(Checker):
             out.append(Finding(
                 RULE, FAULTS_MODULE, 1,
                 f"registered fault point `{point}` has no injection "
-                "site in src/ or benchmarks/ — dead registry entry"))
+                "site under src/, tools/ or examples/ — dead registry entry"))
 
         design = project.text(DESIGN_FILE)
         if design is not None:

@@ -25,7 +25,7 @@ import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 #: subdirectories of the repo root that are scanned for Python modules
-DEFAULT_SUBDIRS = ("src", "benchmarks", "tools", "examples")
+DEFAULT_SUBDIRS = ("src", "tools", "examples")
 
 #: default location of the baseline file, relative to the repo root
 BASELINE_RELPATH = "tools/analysis_baseline.json"

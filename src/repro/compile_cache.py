@@ -1,8 +1,7 @@
 """JAX's persistent compilation cache, placed for the repo's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``) call
-:func:`enable_compile_cache` once, before their first compile; importing
-this module changes nothing.
+The entry point ``chip_smoke.py`` calls :func:`enable_compile_cache`
+once, before its first compile; importing this module changes nothing.
 """
 from __future__ import annotations
 

@@ -215,8 +215,9 @@ class ShardedPlanExecutor:
 
     def decisions(self, host) -> List[Tuple[str, int]]:
         """Per-batch (backend, block_f) under this executor's policy —
-        the plan's stored autotuner decisions when ``host`` carries them
-        (DESIGN.md §14)."""
+        the plan's stored autotuner decisions when ``host`` carries them —
+        checked against ``host``'s tiles before any trace (DESIGN.md
+        §14)."""
         return gnn_policy.batch_decisions(host, self.policy, self.cfg)
 
     # ------------------------------------------------------------- builds

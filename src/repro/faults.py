@@ -40,8 +40,8 @@ Two firing modes, combinable per point:
 * ``script={"forward": [0, 3]}`` — fire on exactly those call indices
   (0-based per point); what the FakeClock test suite uses to place a fault
   on a precise window. When a point has BOTH, they union: scripted indices
-  always fire, every other call falls through to the rate draw — how the
-  chaos bench guarantees at least one injected fault on top of a
+  always fire, every other call falls through to the rate draw — how a
+  chaos drill guarantees at least one injected fault on top of a
   background rate.
 
 The default everywhere is the :data:`NO_FAULTS` singleton whose
@@ -76,8 +76,8 @@ class WorkerDeath(InjectedFault):
 
 #: The canonical registry of injection-point names: point -> where it
 #: fires. Every ``fire``/``delay``/``should_fire`` call site, every
-#: ``rates=``/``script=`` key in src/ and benchmarks/, and the DESIGN.md
-#: §12 table must agree with this dict — enforced both directions by the
+#: ``rates=``/``script=`` key in src/, tools/ and examples/, and the
+#: DESIGN.md §12 table must agree with this dict — enforced both ways by the
 #: ``fault-point`` rule of ``repro.analysis`` and by
 #: ``tools/check_docs_refs.py`` (DESIGN.md §15). Unit tests may exercise
 #: arbitrary point names against a bare ``FaultInjector``; the registry
@@ -180,7 +180,7 @@ class FaultInjector:
         return 0.0
 
     def snapshot(self) -> Dict[str, Dict[str, int]]:
-        """Per-point {calls, fired} — the chaos bench's evidence that the
+        """Per-point {calls, fired} — a chaos drill's evidence that the
         injected failure actually happened."""
         return {p: {"calls": self.calls[p], "fired": self.fired.get(p, 0)}
                 for p in sorted(self.calls)}

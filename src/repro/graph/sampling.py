@@ -4,7 +4,7 @@ All produce the same PaddedBatch format as IBMB so that model/trainer code is
 shared and the comparison is fair (paper: "the same training pipeline for all
 methods"). Methods that resample per epoch are flagged `fixed = False` — their
 per-epoch resampling cost is exactly the overhead the paper attributes to
-them; we measure it in the benchmarks.
+them, and the trainer pays it every epoch.
 
 * NeighborSampling  — GraphSAGE [21]: per-layer fanout sampling per output.
 * LADIES            — [42]: layer-dependent importance sampling (per-batch

@@ -1,6 +1,4 @@
 from repro.models.gnn.models import GNNConfig, init_gnn, gnn_apply
-from repro.models.gnn.ops import validate_batch_for_backend
 from repro.models.gnn.policy import BackendPolicy
 
-__all__ = ["GNNConfig", "init_gnn", "gnn_apply",
-           "validate_batch_for_backend", "BackendPolicy"]
+__all__ = ["GNNConfig", "init_gnn", "gnn_apply", "BackendPolicy"]

@@ -33,9 +33,10 @@ class GNNConfig:
     heads: int = 4               # GAT
     dropout: float = 0.3
     dtype: str = "float32"
-    # aggregation backend: segment | bcsr | dense | auto (DESIGN.md §7/§14);
-    # "auto" resolves per batch (tiles ⇒ bcsr). Deprecated env override
-    # REPRO_GNN_BACKEND. bcsr needs batches built with bcsr_block.
+    # aggregation backend of this executable: segment | bcsr | dense
+    # (DESIGN.md §7). Per-batch dispatch is a policy, not a value here:
+    # repro.models.gnn.policy sets this field per decision (batch_config)
+    # and checks that bcsr batches carry tiles (DESIGN.md §14).
     backend: str = "segment"
     # tuned bcsr feature-tile width (0 = 128-lane default); plan-serving
     # consumers set this per batch from the autotuner's stored decision via
@@ -86,19 +87,6 @@ def init_gnn(cfg: GNNConfig, key) -> Dict:
 
 
 def _gcn_layer(p, h, batch, backend="segment", block_f=0):
-    # §Perf: edge-gather traffic is E×width of whatever flows along edges.
-    # Aggregating in the NARROWER of (d_in, d_out) minimizes it; both orders
-    # are mathematically identical because aggregation is linear:
-    #   agg(h) @ W  ==  agg(h @ W)
-    import os
-    d_in, d_out = p["w"].shape
-    mode = os.environ.get("REPRO_GCN_AGG_ORDER", "transform_first")
-    agg_first = (mode == "agg_first"
-                 or (mode == "auto" and d_in < d_out))
-    if agg_first:
-        h = ops.weighted_agg_backend(h, batch, backend, block_f=block_f)
-        with jax.named_scope("transform"):
-            return h @ p["w"] + p["b"]
     with jax.named_scope("transform"):
         h = h @ p["w"]
     h = ops.weighted_agg_backend(h, batch, backend, block_f=block_f)
@@ -152,8 +140,7 @@ def gnn_apply(cfg: GNNConfig, params: Dict, batch: Dict[str, jnp.ndarray],
     if "edge_mask" not in batch:
         batch = dict(batch)
         batch["edge_mask"] = (batch["edge_weight"] != 0).astype(h.dtype)
-    backend = ops.validate_batch_for_backend(
-        batch, getattr(cfg, "backend", "segment"), cfg.kind)
+    backend = ops.check_backend(getattr(cfg, "backend", "segment"))
     block_f = int(getattr(cfg, "bcsr_block_f", 0))
     for l, p in enumerate(params["layers"]):
         with jax.named_scope(f"layer{l}"):

@@ -16,54 +16,30 @@ Aggregation runs on one of three backends (DESIGN.md §7):
 * "dense"   — materialize the (N, N) batch adjacency and matmul; the
               MXU-roofline upper bound the tiled kernel is judged against.
 
-Selection: ``repro.models.gnn.policy.BackendPolicy`` — fixed per-plan or
-per-batch *auto* from the plan's autotuned decisions (DESIGN.md §14). A
-``GNNConfig.backend`` of ``"auto"`` resolves per batch at trace time by
-tile presence. ``REPRO_GNN_BACKEND`` is a deprecated alias that warns once
-and maps onto a fixed policy. GAT always uses the segment path (its edge
-weights are recomputed by attention every step, so there are no
-precomputable tiles).
+Which backend a batch runs is decided in one place,
+``repro.models.gnn.policy`` (DESIGN.md §14): ``BackendPolicy`` maps each
+batch to a backend (fixed, or the plan's autotuned decisions) and checks
+that a bcsr batch carries tiles. These primitives run the backend they are
+given; :func:`check_backend` refuses any other name. GAT always uses the segment path (its edge weights are recomputed
+by attention every step, so there are no precomputable tiles).
 """
 from __future__ import annotations
-
-import os
-import warnings
 
 import jax
 import jax.numpy as jnp
 
 BACKENDS = ("segment", "bcsr", "dense")
 
-_env_warned = False
 
-
-def _env_backend() -> str:
-    """Deprecated ``REPRO_GNN_BACKEND`` alias — warns ONCE per process and
-    keeps the old force-this-backend semantics (it maps onto
-    ``BackendPolicy.fixed``, so it also overrides auto dispatch)."""
-    global _env_warned
-    name = os.environ.get("REPRO_GNN_BACKEND", "")
-    if name and not _env_warned:
-        warnings.warn(
-            "REPRO_GNN_BACKEND is deprecated: pass "
-            "backend=BackendPolicy.fixed(...) (or a backend name) to the "
-            "trainer/engine/executor instead (DESIGN.md §14)",
-            DeprecationWarning, stacklevel=3)
-        _env_warned = True
+def check_backend(name: str) -> str:
+    """`name` if it is one of :data:`BACKENDS`; a ``ValueError`` otherwise.
+    Per-batch dispatch is a policy, never a backend name."""
+    if name not in BACKENDS:
+        raise ValueError(
+            f"unknown aggregation backend {name!r}; want one of {BACKENDS} "
+            f"— for per-batch dispatch pass backend=BackendPolicy.auto() to "
+            f"the trainer, engine or executor (DESIGN.md §14)")
     return name
-
-
-def resolve_backend(backend: str, allow_auto: bool = False) -> str:
-    """Config value, overridable by the deprecated REPRO_GNN_BACKEND alias
-    (DESIGN.md §7/§14). Resolved at trace time — one executable per backend.
-    ``allow_auto=True`` passes ``"auto"`` through for callers that resolve
-    it per batch (``validate_batch_for_backend``)."""
-    b = _env_backend() or backend or "segment"
-    if allow_auto and b == "auto":
-        return b
-    if b not in BACKENDS:
-        raise ValueError(f"unknown aggregation backend {b!r}; want one of {BACKENDS}")
-    return b
 
 
 def _require_tiles(batch) -> None:
@@ -72,30 +48,6 @@ def _require_tiles(batch) -> None:
             "backend='bcsr' needs tile_cols/tile_vals in the batch — build "
             "batches with bcsr_block set (IBMBConfig(backend='bcsr') or "
             "build_batches(bcsr_block=128)), or use backend='segment'")
-
-
-def validate_batch_for_backend(batch, backend: str, kind: str = "gcn") -> str:
-    """Fail fast (not mid-trace) if `batch` lacks what `backend` needs.
-
-    The public pre-flight check for anything that stages batches for a jit'd
-    forward (``GNNTrainer``, ``GNNInferenceEngine``): resolves the backend
-    (env override included), verifies bcsr tiles are present when required,
-    and returns the resolved backend name. `kind` is the GNN variant — GAT
-    always runs the segment path (DESIGN.md §7), so it needs no tiles.
-
-    ``backend="auto"`` resolves per batch, at trace time, by tile presence
-    (batch *keys* are static under jit): tiles ⇒ bcsr, else segment. This is
-    the degenerate auto mode for raw ``gnn_apply`` callers; plan-serving
-    consumers dispatch on the autotuner's stored per-batch decisions instead
-    (DESIGN.md §14).
-    """
-    b = resolve_backend(backend, allow_auto=True)
-    if b == "auto":
-        has_tiles = "tile_cols" in batch and "tile_vals" in batch
-        b = "bcsr" if (has_tiles and kind != "gat") else "segment"
-    if b == "bcsr" and kind != "gat":
-        _require_tiles(batch)
-    return b
 
 
 def _spmm_impl() -> str:

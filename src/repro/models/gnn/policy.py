@@ -1,28 +1,24 @@
-"""BackendPolicy: the one aggregation-backend resolution entry point
-(DESIGN.md §14).
+"""BackendPolicy: the one place that maps batches to aggregation backends
+and checks them (DESIGN.md §14).
 
-Before this module, three call sites (``GNNTrainer``, ``GNNInferenceEngine``,
-``ShardedPlanExecutor``) each re-implemented the same override dance —
-``backend=str`` → ``dataclasses.replace(model_cfg, backend=...)`` — and the
-only decision surface was a single global string. A per-batch *auto* mode
-cannot live in a global string, so the override arg now accepts a policy:
+A policy is one of:
 
 * ``BackendPolicy.fixed("segment" | "bcsr" | "dense")`` — every batch runs
-  the named backend; exactly the old ``backend="..."`` behaviour.
-* ``BackendPolicy.auto()`` — per-batch dispatch: batches execute on the
-  backend the plan-build autotuner decided for them (``Plan.batch_backends``,
-  driven by the tile-fill/degree stats recorded during preprocessing —
-  ``repro.core.autotune``), falling back to tile presence for raw batch
-  containers that carry no decision.
+  the named backend;
+* ``BackendPolicy.auto()`` — each batch runs the backend the plan-build
+  autotuner decided for it (``Plan.batch_backends``, from the tile-fill and
+  degree stats recorded during preprocessing — ``repro.core.autotune``); a
+  container that stores no decisions dispatches by tile presence.
 
-``resolve(model_cfg, backend)`` is the ONE shared helper: it normalizes a
-``None | str | BackendPolicy`` override (plain strings keep working;
-``"auto"`` means the auto policy), applies the deprecated
-``REPRO_GNN_BACKEND`` env alias (warns once, maps onto a fixed policy), and
-returns the adjusted model config plus the policy. Consumers then key their
-jitted executables by ``(backend, block_f)`` per batch — static shapes per
-backend, so auto dispatch never recompiles beyond one executable per
-distinct decision.
+``GNNTrainer``, ``GNNInferenceEngine`` and ``ShardedPlanExecutor`` take a
+``backend=`` override (``None``, a backend name, ``"auto"`` or a policy)
+and call :func:`resolve` once. Each then gets its per-batch
+``(backend, block_f)`` list, tile check included, from one call to
+:func:`batch_decisions`, and jits one executable per distinct decision
+through :func:`batch_config` — static shapes per backend, so auto dispatch
+never recompiles beyond one executable per decision in play.
+``GNNConfig.backend`` is that per-executable choice, one of
+``ops.BACKENDS``; per-batch dispatch is a policy, never a config value.
 """
 from __future__ import annotations
 
@@ -40,11 +36,7 @@ class BackendPolicy:
 
     @classmethod
     def fixed(cls, name: str) -> "BackendPolicy":
-        if name not in gnn_ops.BACKENDS:
-            raise ValueError(
-                f"unknown aggregation backend {name!r}; want one of "
-                f"{gnn_ops.BACKENDS}")
-        return cls("fixed", name)
+        return cls("fixed", gnn_ops.check_backend(name))
 
     @classmethod
     def auto(cls) -> "BackendPolicy":
@@ -72,24 +64,19 @@ def as_policy(spec: BackendSpec) -> Optional[BackendPolicy]:
 
 
 def resolve(model_cfg, backend: BackendSpec = None):
-    """THE shared resolution helper (replaces the triplicated
-    ``dataclasses.replace(model_cfg, backend=...)`` pattern).
+    """``(model_cfg, policy)`` for one consumer's ``backend`` override.
 
-    Precedence: deprecated ``REPRO_GNN_BACKEND`` env alias (warns once,
-    forces a fixed policy — it predates per-batch dispatch) > explicit
-    ``backend`` arg > ``model_cfg.backend`` (which may itself be ``"auto"``).
-
-    Returns ``(model_cfg, policy)``: for a fixed policy the config's
-    ``backend`` field is the fixed name; for auto it is the ``"segment"``
-    base (always executable — every batch carries COO edges), and consumers
+    An explicit ``backend`` wins over ``model_cfg.backend``, which must be
+    one of ``ops.BACKENDS``: a config naming ``"auto"`` or an unknown
+    backend raises ``ValueError``. For a fixed policy the returned config's
+    ``backend`` is the fixed name; for auto it is the ``"segment"`` base
+    (always executable — every batch carries COO edges), and consumers
     derive per-batch configs via :func:`batch_config`.
     """
-    env = gnn_ops._env_backend()
-    pol = BackendPolicy.fixed(env) if env else as_policy(backend)
-    if pol is None:
-        pol = as_policy(getattr(model_cfg, "backend", "segment") or "segment")
+    name = gnn_ops.check_backend(getattr(model_cfg, "backend", "segment"))
+    pol = as_policy(backend) or BackendPolicy.fixed(name)
     base = "segment" if pol.is_auto else pol.backend
-    if getattr(model_cfg, "backend", None) != base:
+    if name != base:
         model_cfg = dataclasses.replace(model_cfg, backend=base)
     return model_cfg, pol
 
@@ -110,22 +97,11 @@ def _has_tiles(batch) -> bool:
     return "tile_cols" in batch and "tile_vals" in batch
 
 
-def batch_decisions(host, policy: BackendPolicy, model_cfg
-                    ) -> List[Tuple[str, int]]:
-    """Per-batch ``(backend, block_f)`` execution decisions for `host`.
-
-    `host` is anything the trainer/engine serve from: a ``Plan`` (carries
-    the autotuner's v3 decisions), a ``BatchCache``/``LazyBatchCache``, or a
-    plain sequence of batch dicts / ``PaddedBatch``. Fixed policies return a
-    uniform list; the auto policy reads the plan's stored decisions and
-    degrades to tile-presence dispatch for containers without them.
-    GAT has no precomputable tiles, so auto always resolves it to segment.
-    """
+def _decide(host, policy: BackendPolicy, model_cfg) -> List[Tuple[str, int]]:
     n = len(host)
     bf = int(getattr(model_cfg, "bcsr_block_f", 0))
     if not policy.is_auto:
-        be = policy.backend or getattr(model_cfg, "backend", "segment")
-        return [(be, bf)] * n
+        return [(policy.backend, bf)] * n
     if getattr(model_cfg, "kind", "gcn") == "gat":
         return [("segment", 0)] * n
     names = getattr(host, "batch_backends", None)
@@ -134,9 +110,52 @@ def batch_decisions(host, policy: BackendPolicy, model_cfg
         return [(str(b), int(t)) for b, t in zip(names(), tuned)]
     cache = getattr(host, "cache", None)     # Plan-like wrapper
     if cache is not None and host is not cache:
-        return batch_decisions(cache, policy, model_cfg)
+        return _decide(cache, policy, model_cfg)
     return [("bcsr", bf) if _has_tiles(host[i]) else ("segment", 0)
             for i in range(n)]
+
+
+def batch_decisions(host, policy: BackendPolicy, model_cfg,
+                    batcher: Optional[str] = None) -> List[Tuple[str, int]]:
+    """Per-batch ``(backend, block_f)`` execution decisions for `host`,
+    checked against its batches.
+
+    `host` is anything the trainer, engine or executor runs: a ``Plan``
+    (carries the autotuner's v3 decisions), a ``BatchCache``/
+    ``LazyBatchCache``, or a plain sequence of batch dicts /
+    ``PaddedBatch``. Fixed policies return a uniform list; the auto policy
+    reads the plan's stored decisions and dispatches containers without
+    them by tile presence. GAT has no precomputable tiles, so auto always
+    resolves it to segment, and it needs no tiles under any policy.
+
+    Raises ``ValueError`` naming bcsr, before anything is traced, when a
+    bcsr decision falls on a batch without tiles. `batcher` names the
+    resampling batcher that generated `host`, for the message.
+    """
+    decisions = _decide(host, policy, model_cfg)
+    if getattr(model_cfg, "kind", "gcn") == "gat" \
+            or all(b != "bcsr" for b, _ in decisions):
+        return decisions
+    cache = getattr(host, "cache", host)
+    fields = getattr(cache, "fields", None)  # one field set for every batch
+    for i, (b, _) in enumerate(decisions):
+        if b == "bcsr" and not _has_tiles(
+                fields if fields is not None else cache[i]):
+            if batcher is not None:
+                raise ValueError(
+                    f"backend='bcsr' needs batches with precomputed BCSR "
+                    f"tiles, but batcher {batcher!r} (graph/sampling.py) "
+                    f"regenerates batches per epoch without tiles. Train "
+                    f"from an IBMBPipeline plan built with "
+                    f"IBMBConfig(backend='bcsr'), or use backend='segment' "
+                    f"for this batcher (DESIGN.md §7).")
+            raise ValueError(
+                f"backend='bcsr' is decided for batch {i}, which has no "
+                f"tile_cols/tile_vals — build batches with bcsr_block set "
+                f"(IBMBConfig(backend='bcsr') or "
+                f"build_batches(bcsr_block=128)), or use backend='segment' "
+                f"(DESIGN.md §7)")
+    return decisions
 
 
 def superstep_decision(decisions: Sequence[Tuple[str, int]],

@@ -38,7 +38,7 @@ bar the ``tests/test_ooc.py`` equality suite pins.
 The trade is deliberate: the sizing sweep re-derives each batch's induced
 subgraph (and the bcsr pass re-tiles it), so streaming costs roughly one
 extra structure pass of preprocessing time in exchange for O(chunk) peak
-payload memory. ``BENCH_ooc.json`` prices it.
+payload memory.
 """
 from __future__ import annotations
 

@@ -61,7 +61,6 @@ from jax.profiler import TraceAnnotation
 
 from repro.core.plan import Plan
 from repro.serve.common import SystemClock
-from repro.models.gnn import ops as gnn_ops
 from repro.models.gnn import policy as gnn_policy
 from repro.models.gnn.models import GNNConfig, gnn_apply, output_logits
 
@@ -99,14 +98,10 @@ class GNNInferenceEngine:
         # §11) — FakeClock tests can observe deterministic latencies
         self.clock = clock if clock is not None else SystemClock()
         self.cache_batches = max(0, cache_batches)
-        # fail fast at construction, not on the first unlucky query; the
-        # auto policy validates by tile presence (every decision the plan
-        # stored is executable on the batches it stored it for)
-        self._vb = "auto" if self.policy.is_auto else model_cfg.backend
-        gnn_ops.validate_batch_for_backend(plan.cache[0], self._vb,
-                                           model_cfg.kind)
         # per-batch (backend, block_f): the plan's stored autotuner
-        # decisions under an auto policy, uniform under a fixed one
+        # decisions under an auto policy, uniform under a fixed one —
+        # checked against the plan's tiles at construction, not on the
+        # first unlucky query
         self._decisions = gnn_policy.batch_decisions(plan, self.policy,
                                                      model_cfg)
         self._lru: "OrderedDict[int, np.ndarray]" = OrderedDict()
@@ -189,9 +184,11 @@ class GNNInferenceEngine:
         """
         prev = (self.plan, self._lru, self._vstats, self._decisions)
         try:
-            # fail fast, BEFORE touching any serving state
-            gnn_ops.validate_batch_for_backend(
-                plan.cache[0], self._vb, self.cfg.kind)
+            # fail fast, BEFORE touching any serving state: the incoming
+            # plan carries its OWN autotuner decisions (a refresh may
+            # re-decide rebuilt batches, DESIGN.md §14)
+            decisions = gnn_policy.batch_decisions(plan, self.policy,
+                                                   self.cfg)
             if delta is not None:
                 if delta.parent_fingerprint != self.plan.fingerprint:
                     raise ValueError(
@@ -216,10 +213,6 @@ class GNNInferenceEngine:
             keep = OrderedDict((bi, out) for bi, out in self._lru.items()
                                if bi not in dirty and bi < len(plan))
             invalidated = len(self._lru) - len(keep)
-            # the incoming plan carries its OWN autotuner decisions (a
-            # refresh may re-decide rebuilt batches, DESIGN.md §14)
-            decisions = gnn_policy.batch_decisions(plan, self.policy,
-                                                   self.cfg)
             # the actual swap: plan (with routing index) + LRU + per-batch
             # decisions move together
             self.plan, self._lru, self._decisions = plan, keep, decisions

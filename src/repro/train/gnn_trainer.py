@@ -30,7 +30,6 @@ from repro.core.plan import Plan
 from repro.core.scheduling import make_schedule
 from repro.data.loader import PrefetchLoader
 from repro.faults import FaultStats
-from repro.models.gnn import ops as gnn_ops
 from repro.models.gnn import policy as gnn_policy
 from repro.models.gnn.models import (
     GNNConfig, init_gnn, gnn_apply, output_logits, masked_xent, masked_accuracy,
@@ -274,36 +273,19 @@ class GNNTrainer:
             preprocess_time = train_batches.timings.get(
                 f"preprocess/{m.get('split')}/{m.get('mode')}", 0.0)
         fixed = isinstance(train_batches, (Plan, BatchCache, list, tuple))
-        if not fixed and self.cfg.kind != "gat" \
-                and gnn_ops.resolve_backend(self.cfg.backend) == "bcsr":
-            # fail with the batcher's name up front, not with a generic
-            # missing-tiles error from deep inside the first epoch's trace
-            name = getattr(train_batches, "name",
-                           type(train_batches).__name__)
-            raise ValueError(
-                f"backend='bcsr' needs batches with precomputed BCSR tiles, "
-                f"but batcher {name!r} (graph/sampling.py) regenerates "
-                f"batches per epoch without tiles. Train from an "
-                f"IBMBPipeline plan built with IBMBConfig(backend='bcsr'), "
-                f"or use backend='segment' for this batcher (DESIGN.md §7).")
+        # (backend, block_f) per batch — the plan's stored autotuner
+        # decisions under an auto policy, uniform otherwise — checked
+        # against the batches' tiles before anything is traced (§14)
         if fixed:
             host = as_host_batches(train_batches)
             labels = _batch_labels(train_batches)
             order_fn = lambda ep: make_schedule(
                 labels, num_classes, mode=schedule_mode, seed=self.seed + ep)
-            # (backend, block_f) per batch — the plan's stored autotuner
-            # decisions under an auto policy, uniform otherwise (§14)
             decisions = gnn_policy.batch_decisions(
                 train_batches, self.policy, self.cfg)
         val_host = as_host_batches(val_batches)
         val_decisions = gnn_policy.batch_decisions(
             val_batches, self.policy, self.cfg)
-        # fail fast (not mid-trace) if the batches lack the tiles the
-        # configured backend needs (DESIGN.md §7); an auto policy validates
-        # by tile presence, so any batch container passes
-        vb = "auto" if self.policy.is_auto else self.cfg.backend
-        for sample in ([host[0]] if fixed else []) + [val_host[0]]:
-            gnn_ops.validate_batch_for_backend(sample, vb, self.cfg.kind)
 
         executor = None
         if mesh is not None:
@@ -345,7 +327,9 @@ class GNNTrainer:
                     host = as_host_batches(epoch_pb)
                     order = np.random.default_rng(self.seed + ep).permutation(len(host))
                     decisions = gnn_policy.batch_decisions(
-                        epoch_pb, self.policy, self.cfg)
+                        epoch_pb, self.policy, self.cfg,
+                        batcher=getattr(train_batches, "name",
+                                        type(train_batches).__name__))
                 else:
                     with TraceAnnotation("ibmb/fit/schedule"):
                         order = order_fn(ep)

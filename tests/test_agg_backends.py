@@ -10,7 +10,6 @@ from repro.core import IBMBPipeline, IBMBConfig
 from repro.core.batches import batch_node_order, build_batches
 from repro.kernels.spmm.ops import csr_to_bcsr, spmm_bcsr, spmm_bcsr_sym
 from repro.models.gnn import GNNConfig, init_gnn, gnn_apply
-from repro.models.gnn.ops import resolve_backend
 
 
 @pytest.fixture(scope="module")
@@ -101,13 +100,17 @@ def test_bcsr_backend_requires_tiles(tiny_ds):
         gnn_apply(cfg, params, bd)
 
 
-def test_env_override_resolves_backend(monkeypatch):
-    assert resolve_backend("segment") == "segment"
-    monkeypatch.setenv("REPRO_GNN_BACKEND", "dense")
-    assert resolve_backend("segment") == "dense"
-    monkeypatch.setenv("REPRO_GNN_BACKEND", "nope")
-    with pytest.raises(ValueError):
-        resolve_backend("segment")
+@pytest.mark.parametrize("name", ["auto", "warp"])
+def test_forward_refuses_a_name_outside_backends(tiny_ds, bcsr_batches,
+                                                 name):
+    # a raw forward runs the backend it is given and refuses any other name
+    # rather than falling through to segment; per-batch dispatch is a policy
+    cfg = GNNConfig(kind="gcn", in_dim=tiny_ds.feat_dim, hidden=32,
+                    out_dim=tiny_ds.num_classes, num_layers=2, backend=name)
+    params = init_gnn(cfg, jax.random.PRNGKey(0))
+    bd = bcsr_batches[0].device_arrays()
+    with pytest.raises(ValueError, match=r"BackendPolicy\.auto\(\)"):
+        gnn_apply(cfg, params, bd)
 
 
 # ------------------------------------------------------------ conversion

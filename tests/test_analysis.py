@@ -13,7 +13,6 @@ import pytest
 
 from conftest import FakeClock
 from repro.analysis.atomic_write import AtomicWriteChecker
-from repro.analysis.bench_gate import BenchGateChecker
 from repro.analysis.cli import find_repo_root, main
 from repro.analysis.determinism import DeterminismChecker
 from repro.analysis.fault_points import FaultPointChecker
@@ -138,22 +137,6 @@ def test_fault_registry_in_sync_is_silent():
     assert _run(FaultPointChecker, proj) == []
 
 
-# ----------------------------------------------------------------- bench gate
-def test_bench_gate_drift_fires():
-    proj = Project.load(str(FIXTURES / "benchgate_bad"))
-    found = _run(BenchGateChecker, proj)
-    assert len(found) == 2
-    joined = "\n".join(f.message for f in found)
-    assert "`x/missing`" in joined
-    assert "`t/pre_`" in joined
-
-
-def test_bench_gate_silent_when_rows_emitted():
-    # exact literals plus an f-string prefix both count as emitters
-    proj = Project.load(str(FIXTURES / "benchgate_good"))
-    assert _run(BenchGateChecker, proj) == []
-
-
 # ------------------------------------------------------ suppression semantics
 _ALLOW_SRC = """\
 import time
@@ -231,7 +214,7 @@ def test_cli_nonzero_exit_and_json_report_on_drift(capsys):
 def test_cli_only_and_path_filters(capsys):
     # --only selects checkers; a path argument narrows reported findings
     rc = main(["--root", str(FIXTURES / "faultreg_bad"),
-               "--only", "bench-gate"])
+               "--only", "jit-cache"])
     capsys.readouterr()
     assert rc == 0
     rc = main(["--root", str(FIXTURES / "faultreg_bad"), "tools"])

@@ -7,7 +7,6 @@ dispatch parity through the engine, the trainer, and the shard_map executor
 """
 import dataclasses
 import json
-import warnings
 
 import jax
 import numpy as np
@@ -20,7 +19,6 @@ from repro.core.plan import BACKEND_CODES, decode_backends, encode_backends
 from repro.graph.csr import coo_to_csr, make_undirected
 from repro.kernels.spmm import csr_to_bcsr, spmm_bcsr, spmm_bcsr_sym
 from repro.models.gnn import GNNConfig, init_gnn
-from repro.models.gnn import ops as gnn_ops
 from repro.models.gnn import policy as gnn_policy
 from repro.models.gnn.policy import BackendPolicy
 from repro.serve import GNNInferenceEngine
@@ -82,24 +80,17 @@ def test_resolve_precedence_and_auto_base():
     # auto resolves the config to the always-executable segment base
     c, p = gnn_policy.resolve(cfg, BackendPolicy.auto())
     assert c.backend == "segment" and p.is_auto
-    # a config may itself ask for auto
-    c, p = gnn_policy.resolve(dataclasses.replace(cfg, backend="auto"))
-    assert c.backend == "segment" and p.is_auto
 
 
-def test_env_alias_forces_fixed_and_warns_once(monkeypatch):
-    monkeypatch.setenv("REPRO_GNN_BACKEND", "dense")
-    monkeypatch.setattr(gnn_ops, "_env_warned", False)
-    cfg = GNNConfig(kind="gcn", in_dim=4, hidden=8, out_dim=2, num_layers=2)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        c, p = gnn_policy.resolve(cfg, BackendPolicy.auto())
-        gnn_policy.resolve(cfg, "bcsr")
-    # the deprecated alias overrides even an explicit auto/bcsr override...
-    assert p == BackendPolicy.fixed("dense") and c.backend == "dense"
-    # ...and deprecation-warns exactly once per process
-    dep = [x for x in w if issubclass(x.category, DeprecationWarning)]
-    assert len(dep) == 1 and "REPRO_GNN_BACKEND" in str(dep[0].message)
+@pytest.mark.parametrize("name", ["auto", "warp"])
+def test_config_backend_outside_backends_is_refused(name):
+    """Per-batch dispatch is a policy, never a config value: a config that
+    names "auto" (or no backend at all) is refused when it is resolved, and
+    the message points at the policy."""
+    cfg = GNNConfig(kind="gcn", in_dim=4, hidden=8, out_dim=2, num_layers=2,
+                    backend=name)
+    with pytest.raises(ValueError, match=r"BackendPolicy\.auto\(\)"):
+        gnn_policy.resolve(cfg)
 
 
 def test_batch_config_is_noop_when_matching():
@@ -444,3 +435,82 @@ def test_gat_auto_resolves_to_segment(tiny_ds):
     cfg = _cfg(tiny_ds, kind="gat", heads=2)
     decs = gnn_policy.batch_decisions(plan, BackendPolicy.auto(), cfg)
     assert decs == [("segment", 0)] * len(plan)
+
+
+# ------------------------------------- one decision list for every consumer
+MIXED = dict(autotune=True, auto_kappa=16.0, tune_block_fs=(16, 32))
+
+
+def _no_trace(*a, **k):
+    raise AssertionError("the forward was traced before the tile check")
+
+
+def _fit_tileless_plan(tiny_ds, cfg, val, monkeypatch):
+    import repro.train.gnn_trainer as gt
+    monkeypatch.setattr(gt, "gnn_apply", _no_trace)
+    GNNTrainer(cfg, backend=BackendPolicy.fixed("bcsr")).fit(
+        _pipe(tiny_ds, backend="segment").plan("train"), val,
+        tiny_ds.num_classes, epochs=1)
+
+
+def _fit_batcher(tiny_ds, cfg, val, monkeypatch):
+    import repro.train.gnn_trainer as gt
+    from repro.graph.sampling import make_batcher
+    monkeypatch.setattr(gt, "gnn_apply", _no_trace)
+    bt = make_batcher("cluster_gcn", tiny_ds, split="train", num_batches=2)
+    GNNTrainer(cfg, backend=BackendPolicy.fixed("bcsr")).fit(
+        bt, val, tiny_ds.num_classes, epochs=1)
+
+
+def _executor_evaluate(tiny_ds, cfg, val, monkeypatch):
+    import repro.dist.data_parallel as dp
+    monkeypatch.setattr(dp, "gnn_apply", _no_trace)
+    ex = dp.ShardedPlanExecutor(dp.data_mesh(1), cfg,
+                                backend=BackendPolicy.fixed("bcsr"))
+    seg = _pipe(tiny_ds, backend="segment").plan("val", for_inference=True)
+    ex.evaluate(init_gnn(cfg, jax.random.PRNGKey(0)), seg.cache)
+
+
+@pytest.mark.parametrize("consumer", [_fit_tileless_plan, _fit_batcher,
+                                      _executor_evaluate],
+                         ids=["fit-plan", "fit-batcher", "executor"])
+def test_bcsr_on_tileless_batches_fails_before_trace(tiny_ds, monkeypatch,
+                                                     consumer):
+    """A fixed bcsr policy over batches without tiles is refused by the
+    policy's one check, before any forward is traced."""
+    val = _pipe(tiny_ds).plan("val", for_inference=True)     # tiled
+    with pytest.raises(ValueError, match="bcsr"):
+        consumer(tiny_ds, _cfg(tiny_ds), val, monkeypatch)
+
+
+@pytest.mark.parametrize("consumer", ["trainer", "engine", "executor"])
+def test_consumers_dispatch_the_stored_decisions(tiny_ds, consumer):
+    """Under the auto policy every consumer runs, batch by batch, the
+    (backend, block_f) the plan stored: a plan that mixes backends and
+    tuned widths gives each the same list."""
+    from repro.dist.data_parallel import ShardedPlanExecutor, data_mesh
+    plan = _pipe(tiny_ds, **MIXED).plan("train")
+    want = list(zip(plan.batch_backends(),
+                    (int(x) for x in plan.batch_block_fs())))
+    assert {b for b, _ in want} == {"segment", "bcsr"}
+    assert {f for _, f in want} == {0, 32}
+    cfg = _cfg(tiny_ds)
+    params = init_gnn(cfg, jax.random.PRNGKey(0))
+    if consumer == "trainer":
+        tr = GNNTrainer(cfg, backend=BackendPolicy.auto())
+        got = []
+
+        def steps_for(backend, block_f=0):
+            got.append((backend, block_f))
+            return {"eval": lambda params, batch: (0.0, 0.0, 1.0)}
+
+        tr._steps_for = steps_for
+        tr.evaluate(params, plan)
+    elif consumer == "engine":
+        got = GNNInferenceEngine(plan, cfg, params,
+                                 backend=BackendPolicy.auto())._decisions
+    else:
+        got = ShardedPlanExecutor(data_mesh(1), cfg,
+                                  backend=BackendPolicy.auto()
+                                  ).decisions(plan)
+    assert got == want
